@@ -7,7 +7,9 @@ cheap across processes.  This bench measures exactly that boundary:
 
 * **cold vs warm time-to-first-TTI** — the same small
   ``MeshSlotScheduler`` workload runs in two *fresh subprocesses*
-  sharing one ``REPRO_XLA_CACHE`` directory.  The first (cold) process
+  sharing one ``JAX_COMPILATION_CACHE_DIR`` (the fixed directory
+  ``.cache/bench-compile-xla`` in the checkout, emptied at the start of
+  each run).  The first (cold) process
   compiles every step; the second (warm) process must reach its first
   served TTI with **zero new XLA compilations** (``executables_compiled
   == 0``, ``cache_hits`` == executables needed) and a measurably smaller
@@ -24,21 +26,23 @@ Flags:
   --smoke   the two-process cold/warm gate + steady-state parity with
             one fewer tick — the CI cold-start gate; writes no JSON.
   --child   internal: run the child workload and print its stats JSON
-            (spawned by the parent with ``REPRO_XLA_CACHE`` pointed at
-            the shared tmp dir).
+            (spawned by the parent with ``JAX_COMPILATION_CACHE_DIR``
+            pointed at the shared cache dir).
 """
 import argparse
 import json
 import os
+import shutil
 import statistics
 import subprocess
 import sys
-import tempfile
 import time
 
 from benchmarks.common import emit, emit_json
 
 JSON_PATH = "experiments/phy/compile.json"
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CACHE_DIR = os.path.join(ROOT, ".cache", "bench-compile-xla")
 CHILD_MARK = "COMPILE_CHILD_JSON "
 N_CELLS = 2
 N_TICKS = 4
@@ -85,15 +89,14 @@ def _child_workload() -> dict:
 
 def _spawn_child(cache_dir: str) -> dict:
     env = dict(os.environ)
-    env["REPRO_XLA_CACHE"] = cache_dir
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root]
+        [os.path.join(ROOT, "src"), ROOT]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
     )
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_compile", "--child"],
-        capture_output=True, text=True, env=env, cwd=root, check=True,
+        capture_output=True, text=True, env=env, cwd=ROOT, check=True,
     )
     for line in reversed(out.stdout.splitlines()):
         if line.startswith(CHILD_MARK):
@@ -103,9 +106,9 @@ def _spawn_child(cache_dir: str) -> dict:
 
 def bench_cold_warm() -> dict:
     """Cold then warm fresh-process runs over one shared cache dir."""
-    with tempfile.TemporaryDirectory(prefix="repro-xla-") as cache:
-        cold = _spawn_child(cache)
-        warm = _spawn_child(cache)
+    shutil.rmtree(CACHE_DIR, ignore_errors=True)  # the cold run starts empty
+    cold = _spawn_child(CACHE_DIR)
+    warm = _spawn_child(CACHE_DIR)
     needed = cold["executables_compiled"] + cold["cache_hits"]
     emit("compile/cold_ttf", cold["time_to_first_tti_s"] * 1e6,
          f"compiled={cold['executables_compiled']} "

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.runtime import compiler_params, resolve_interpret
+from repro.kernels.runtime import resolve_interpret
 
 
 def _dwconv_kernel(x_ref, dw_ref, pw_ref, g_ref, b_ref, o_ref, acc_ref, *,
@@ -91,7 +91,7 @@ def dwconv_block(
         out_specs=pl.BlockSpec((1, h, w, f), lambda bi, ci: (bi, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((h * w, f), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.runtime import compiler_params, resolve_interpret
+from repro.kernels.runtime import resolve_interpret
 
 
 def _fc_softmax_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -69,7 +69,7 @@ def fc_softmax(
         out_specs=pl.BlockSpec((bm, n), lambda i, kk: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, n), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

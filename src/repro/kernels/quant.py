@@ -8,10 +8,7 @@ shares, so the parity tests, the energy model, and the tune-cache keys all
 agree on what "int8" or "fp8" means:
 
 * **Precision names** — ``fp32 | fp16 | bf16 | int8 | fp8``.  ``fp8`` means
-  e4m3 where :data:`jnp.float8_e4m3fn` exists and falls back to int8
-  *storage* otherwise (the precision name sticks, so the energy model still
-  prices it as fp8 — the fallback is a host-dtype limitation, not a model
-  choice).
+  e4m3 (:data:`jnp.float8_e4m3fn`).
 * **Scales** — symmetric, absmax-based, fp32, computed per-axis (per-row
   activations / per-column weights for GEMM, per-(batch*head) for MHA) and
   kept *outside* the quantized tensor so dequant is a rank-1 multiply in
@@ -27,10 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# e4m3 "fn" variant: finite-only, max normal 448.  Older jax builds lack
-# the dtype entirely — gate, never import-error (int8 storage fallback).
-FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
-HAS_FP8 = FP8_DTYPE is not None
+# e4m3 "fn" variant: finite-only, max normal 448
+FP8_DTYPE = jnp.float8_e4m3fn
 FP8_MAX = 448.0
 INT8_MAX = 127.0
 
@@ -72,17 +67,13 @@ def is_quantized(precision: Optional[str]) -> bool:
 
 
 def storage_dtype(precision: Optional[str]):
-    """The jnp dtype quantized values are *stored* in (fp8 -> int8 when the
-    jax build lacks float8_e4m3fn)."""
+    """The jnp dtype quantized values are *stored* in."""
     p = resolve_precision(precision)
-    if p == "fp8":
-        return FP8_DTYPE if HAS_FP8 else jnp.int8
-    return _STORAGE[p]
+    return FP8_DTYPE if p == "fp8" else _STORAGE[p]
 
 
 def itemsize(precision: Optional[str]) -> int:
-    """Modeled storage bytes per element (fp8 counts 1 even on the int8
-    fallback — it *is* 1)."""
+    """Modeled storage bytes per element (1 for both 1-byte policies)."""
     p = resolve_precision(precision)
     return 1 if p in QUANTIZED else jnp.dtype(_STORAGE[p]).itemsize
 

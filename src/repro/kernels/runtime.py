@@ -1,22 +1,15 @@
-"""Shared Pallas runtime helpers for the kernel modules.
+"""Shared Pallas runtime helper for the kernel modules.
 
-* ``resolve_interpret`` — kernels take ``interpret=None`` and auto-select:
-  interpreter mode everywhere except a real TPU backend, so the same call
-  sites validate on CPU CI and compile to Mosaic on hardware.
-* ``compiler_params`` — version-compat constructor for the TPU compiler
-  params class (renamed ``TPUCompilerParams`` -> ``CompilerParams`` across
-  JAX releases).
+``resolve_interpret`` — kernels take ``interpret=None`` and auto-select:
+interpreter mode everywhere except a real TPU backend, so the same call
+sites validate on CPU and compile to Mosaic on the chip.  On a TPU backend
+a kernel never runs interpreted unless its caller asks for it.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-_COMPILER_PARAMS_CLS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -24,7 +17,3 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
-
-
-def compiler_params(**kwargs):
-    return _COMPILER_PARAMS_CLS(**kwargs)

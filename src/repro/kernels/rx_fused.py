@@ -38,7 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import quant, tune
-from repro.kernels.runtime import compiler_params, resolve_interpret
+from repro.kernels.runtime import resolve_interpret
 
 
 def _use_pallas(use_pallas: Optional[bool]) -> bool:
@@ -372,10 +372,11 @@ def _demap_pallas(
             jax.ShapeDtypeStruct((2 * n_tx, b, n_sym, n_sc), f32),
             jax.ShapeDtypeStruct((n_tx, b, n_sym, n_sc), f32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name=tune_op,
     )(yp.astype(f32), hp.astype(f32), nv2d)
 
     x_hat = jnp.moveaxis(xh_p[:n_tx] + 1j * xh_p[n_tx:], 0, -1)
@@ -625,8 +626,9 @@ def ls_che_pallas(
         ],
         out_specs=pl.BlockSpec((2 * n_tx, bm, n_sc), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((2 * n_tx, rows, n_sc), f32),
-        compiler_params=compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="rx_ls_che",
     )(yc.astype(f32), opp.astype(f32))
 
     h = (out[:n_tx] + 1j * out[n_tx:]).reshape(n_tx, b, n_rx, n_sc)

@@ -30,7 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.balance import gemm_tile_balance, tile_vmem_bytes
 from repro.core.machine import TPU_V5E, Machine
 from repro.kernels import quant, tune
-from repro.kernels.runtime import compiler_params, resolve_interpret
+from repro.kernels.runtime import resolve_interpret
 
 
 def _dtype_key(dtype_or_bytes) -> tuple[str, int]:
@@ -163,7 +163,7 @@ def te_gemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -283,7 +283,7 @@ def te_gemm_quant(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,10 +23,13 @@ Cache file format (JSON)::
       }
     }
 
-The default path is ``~/.cache/repro-tensorpool/tune.json``; override with
-the ``REPRO_TUNE_CACHE`` environment variable or :func:`set_cache_path`
-(tests use a tmp path).  Lookups are tolerant: a missing/corrupt cache or a
-stale entry that no longer divides the problem shape is ignored.
+The cache persists only to a file named by the ``REPRO_TUNE_CACHE``
+environment variable or :func:`set_cache_path` (tests use a tmp path).
+With neither, it lives in memory for the process, so kernels run on their
+static default tilings plus whatever this process tuned — never on a file
+left somewhere outside the checkout.  Lookups are tolerant: a
+missing/corrupt cache or a stale entry that no longer divides the problem
+shape is ignored.
 """
 from __future__ import annotations
 
@@ -42,27 +45,9 @@ _ORIG_ENV = os.environ.get(_ENV_VAR)  # restored by set_cache_path(None)
 _VERSION = 1
 
 
-def repro_cache_path(env_var: str, *leaf: str) -> str:
-    """Resolve a cache location under the shared ``REPRO_*`` convention.
-
-    The environment variable wins outright (tests and CI point it at tmp
-    dirs); otherwise the cache lives under
-    ``~/.cache/repro-tensorpool/<leaf...>``.  Shared by this module's
-    tuning cache (``REPRO_TUNE_CACHE``) and the AOT executable registry's
-    persistent XLA compilation cache (``REPRO_XLA_CACHE``,
-    :mod:`repro.serve.exec_registry`), so every on-disk cache follows one
-    override story.
-    """
-    return os.environ.get(
-        env_var,
-        os.path.join(
-            os.path.expanduser("~"), ".cache", "repro-tensorpool", *leaf
-        ),
-    )
-
-
-def default_cache_path() -> str:
-    return repro_cache_path(_ENV_VAR, "tune.json")
+def default_cache_path() -> Optional[str]:
+    """``$REPRO_TUNE_CACHE``, or None (an in-memory cache)."""
+    return os.environ.get(_ENV_VAR)
 
 
 def cache_key(op: str, shape: Sequence[int], extra: str = "",
@@ -85,6 +70,8 @@ class TuneCache:
     def _load(self) -> dict:
         if self._entries is None:
             self._entries = {}
+            if self.path is None:
+                return self._entries
             try:
                 with open(self.path) as f:
                     data = json.load(f)
@@ -99,7 +86,10 @@ class TuneCache:
         ``os.replace`` it over the target, so an interrupted or
         concurrent run can never leave a truncated cache behind (a
         corrupt file would otherwise poison block-shape selection until
-        manually deleted — ``_load`` regenerates from empty instead)."""
+        manually deleted — ``_load`` regenerates from empty instead).
+        An in-memory cache (no path) has nothing to persist."""
+        if self.path is None:
+            return
         d = os.path.dirname(self.path) or "."
         os.makedirs(d, exist_ok=True)
         payload = {"version": _VERSION, "entries": self._load()}
@@ -389,7 +379,10 @@ def autotune_ldpc(batch: int, code, *, max_iters: int = 12,
     llr = _coding.derate_match(
         code, ((2.0 * cw - 1.0) * 3.0 + noise)[..., : code.e_bits]
     )
-    cands = [(bt,) for bt in _divisor_cands(batch, (128, 64, 32, 16, 8, 4))]
+    # lane tiles are whole multiples of 128 codewords (Mosaic's lane
+    # quantum); the kernel pads the batch up to a whole tile
+    padded = -(-batch // _ldpc.LANE) * _ldpc.LANE
+    cands = [(bt,) for bt in _divisor_cands(padded, (512, 256, 128))]
     return autotune(
         "ldpc_decode", (code.k_b, code.m_b, code.z, max_iters), cands,
         lambda c: _ldpc.ldpc_decode_pallas(

@@ -142,8 +142,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     mem = compiled.memory_analysis()
     try:
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
     except Exception:
         ca = {}
     text = compiled.as_text()

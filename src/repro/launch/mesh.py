@@ -19,12 +19,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with AxisType compat (absent on older jax releases)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis in Auto sharding mode."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

@@ -14,7 +14,8 @@ crash recovery.
 Every compiled serving step is owned by the process-wide AOT executable
 registry (:mod:`repro.serve.exec_registry`): keyed by (scenario, receiver,
 precision, batch bucket, backend), populated ahead of the first TTI,
-backed by a persistent on-disk compilation cache (``REPRO_XLA_CACHE``),
+backed by a persistent on-disk compilation cache
+(``$JAX_COMPILATION_CACHE_DIR``, else ``.cache/jax`` in the checkout),
 with pluggable batch-bucketing policies (:class:`PowerOfTwoBuckets`,
 :class:`FixedBuckets`, :class:`CostModelBuckets`)."""
 from repro.serve.engine import ServeEngine, Request

@@ -23,14 +23,13 @@ This module centralizes all of it:
   and cache hits are accounted both registry-wide and into per-engine
   :class:`ExecStats` accumulators that surface on every serve report.
 * **Persistent compilation cache** — the registry wires jax's on-disk XLA
-  cache under the same env convention as the kernel autotuner
-  (:func:`repro.kernels.tune.repro_cache_path`): ``REPRO_XLA_CACHE``
-  overrides, default ``~/.cache/repro-tensorpool/xla``.  A cold process
-  restart then re-serves without recompiling: every ``compile()`` that the
-  disk cache satisfies counts as a ``cache_hit`` instead of an
-  ``executables_compiled``.  The cache is attached only around the
-  registry's own builds — jits outside the registry never round-trip the
-  serializer (see :func:`enable_persistent_cache`).
+  cache to ``$JAX_COMPILATION_CACHE_DIR`` when that is set, and otherwise
+  to one fixed directory inside the checkout (``.cache/jax``).  A cold
+  process restart then re-serves without recompiling: every ``compile()``
+  that the disk cache satisfies counts as a ``cache_hit`` instead of an
+  ``executables_compiled``.  Without the variable the cache is attached
+  only around the registry's own builds — jits outside the registry never
+  round-trip the serializer (see :func:`enable_persistent_cache`).
 * :class:`BucketPolicy` — batch-bucketing as an explicit pluggable policy
   (:class:`PowerOfTwoBuckets`, :class:`FixedBuckets`,
   :class:`CostModelBuckets`) instead of logic inlined in the mesh lane
@@ -54,13 +53,22 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import os
+import pathlib
 import time
 from typing import Callable, Optional
 
 import jax
+import jax.monitoring
 import numpy as np
+from jax import shard_map
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
-_ENV_VAR = "REPRO_XLA_CACHE"
+_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# src/repro/serve/exec_registry.py -> the checkout root
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 __all__ = [
     "BucketPolicy", "CostModelBuckets", "ExecKey", "ExecRegistry",
@@ -72,10 +80,11 @@ __all__ = [
 
 
 def default_cache_dir() -> str:
-    """Where the persistent XLA compilation cache lives (env-overridable)."""
-    from repro.kernels.tune import repro_cache_path
-
-    return repro_cache_path(_ENV_VAR, "xla")
+    """Where the persistent XLA compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``.cache/jax`` in the
+    checkout.  The path is part of the cache's key, so it is fixed — never
+    a temporary, per-process or per-run name."""
+    return os.environ.get(_ENV_VAR) or str(_CHECKOUT / ".cache" / "jax")
 
 
 # ---------------------------------------------------------------------------
@@ -91,6 +100,7 @@ def default_cache_dir() -> str:
 _EVENTS = {"hits": 0, "misses": 0}
 _LISTENING = False
 _ACTIVE_DIR: Optional[str] = None
+_PREV_DIR: Optional[str] = None  # jax's own setting, restored on detach
 
 
 def _event_listener(event: str, *a, **kw) -> None:
@@ -102,15 +112,9 @@ def _event_listener(event: str, *a, **kw) -> None:
 
 def _ensure_listener() -> None:
     global _LISTENING
-    if _LISTENING:
-        return
-    try:
-        from jax._src import monitoring
-
-        monitoring.register_event_listener(_event_listener)
+    if not _LISTENING:
+        jax.monitoring.register_event_listener(_event_listener)
         _LISTENING = True
-    except Exception:
-        pass  # counters degrade to zero; serving still works
 
 
 def enable_persistent_cache(path: Optional[str] = None) -> str:
@@ -119,57 +123,48 @@ def enable_persistent_cache(path: Optional[str] = None) -> str:
     Thresholds are zeroed so even fast-compiling mesh steps persist —
     cold-restart time-to-first-slot is the point, not disk frugality.
     Changing the directory mid-process resets the cache singleton so the
-    new location takes effect (tests swap dirs via ``REPRO_XLA_CACHE``).
+    new location takes effect.
 
     The registry attaches the cache only around its own builds (see
-    :meth:`ExecRegistry.acquire`) and detaches it afterwards with
-    :func:`disable_persistent_cache` — leaving it attached process-wide
-    makes *unrelated* jits round-trip the serializer too, and on the CPU
-    backend an executable with donated arguments compiled that way can
-    free buffers still referenced by zero-copy host views (observed as a
+    :meth:`ExecRegistry.acquire`) and :func:`disable_persistent_cache`
+    then restores jax's own setting: none, unless
+    ``JAX_COMPILATION_CACHE_DIR`` attached the same directory process-wide
+    at start-up.  Attaching the checkout cache process-wide would make
+    *unrelated* jits round-trip the serializer too, and on the CPU backend
+    an executable with donated arguments compiled that way can free
+    buffers still referenced by zero-copy host views (observed as a
     segfault when a donated train step runs next to ``np.savez``
     checkpoint snapshots).  Serving compiles all funnel through the
     registry, so scoping loses nothing.
     """
-    global _ACTIVE_DIR
+    global _ACTIVE_DIR, _PREV_DIR
     path = path or default_cache_dir()
     if _ACTIVE_DIR == path:
         return path
     _ensure_listener()
+    if _ACTIVE_DIR is None:
+        _PREV_DIR = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:
-        pass  # knob absent on older jax: executable cache still persists
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+    compilation_cache.reset_cache()
     _ACTIVE_DIR = path
     return path
 
 
 def disable_persistent_cache() -> None:
-    """Detach the persistent compilation cache (idempotent).
+    """Detach the registry's cache directory (idempotent).
 
-    Leaves the threshold knobs in place — with no cache directory they
-    are inert — and resets the cache singleton so a later
-    :func:`enable_persistent_cache` re-attaches cleanly.
+    Restores the directory jax had before :func:`enable_persistent_cache`
+    and resets the cache singleton so a later enable re-attaches cleanly.
+    The threshold knobs stay zeroed.
     """
     global _ACTIVE_DIR
     if _ACTIVE_DIR is None:
         return
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+    jax.config.update("jax_compilation_cache_dir", _PREV_DIR)
+    compilation_cache.reset_cache()
     _ACTIVE_DIR = None
 
 
@@ -182,7 +177,9 @@ class ExecKey:
     """Stable identity of one compiled serving step.
 
     ``lanes == 0`` is a single-cell step (no vmapped lane axis);
-    ``lanes > 0`` is a mesh step over that lane bucket.  ``variant``
+    ``lanes > 0`` is a mesh step over that lane bucket, compiled for the
+    device mesh named by ``mesh`` (its shape and device ids: the same
+    bucket on another mesh is another executable).  ``variant``
     fingerprints the pipeline beyond its display name (stage structure +
     neural-weight digest) so builder options that change the computation
     — ``mmse_smooth``, custom params — never collide.  ``schema`` names
@@ -198,12 +195,14 @@ class ExecKey:
     variant: str = ""
     donate: bool = False
     schema: str = ""
+    mesh: str = ""
 
     def __str__(self) -> str:
         return "|".join((
             self.scenario, self.receiver, self.precision,
             f"b{self.batch}", f"l{self.lanes}", self.backend,
             self.variant, "donate" if self.donate else "keep", self.schema,
+            self.mesh,
         ))
 
 
@@ -275,8 +274,11 @@ def _pipeline_variant(pipeline) -> str:
 
 def exec_key_for(pipeline, batch: int, *, lanes: int = 0,
                  donate: bool = False, schema: str = "",
-                 backend: Optional[str] = None) -> ExecKey:
+                 backend: Optional[str] = None, mesh=None) -> ExecKey:
     """The :class:`ExecKey` of ``pipeline``'s step at (batch, lanes)."""
+    mesh_tag = "" if mesh is None else "x".join(
+        str(d) for d in mesh.devices.shape
+    ) + "@" + ",".join(str(d.id) for d in mesh.devices.flat)
     return ExecKey(
         scenario=pipeline.scenario.name,
         receiver=pipeline.name,
@@ -287,7 +289,43 @@ def exec_key_for(pipeline, batch: int, *, lanes: int = 0,
         variant=_pipeline_variant(pipeline),
         donate=bool(donate),
         schema=schema,
+        mesh=mesh_tag,
     )
+
+
+def _example_mesh(example: dict):
+    """The one device mesh a staged example is sharded over, or None."""
+    meshes = {
+        v.sharding.mesh for v in example.values()
+        if isinstance(getattr(v, "sharding", None), NamedSharding)
+    }
+    if len(meshes) > 1:
+        raise ValueError(f"example spans {len(meshes)} device meshes")
+    return meshes.pop() if meshes else None
+
+
+def mesh_step(fn: Callable, example: dict, mesh) -> Callable:
+    """``fn`` (one lane-vmapped step) as a ``shard_map`` over ``mesh``.
+
+    Every lane and every slot of a lane is independent, so each device
+    runs the step on its own (cell, batch) block, with no collective.
+    This is required, not an optimization: the partitioner cannot split
+    a Mosaic kernel, so a step holding one does not compile on a
+    multi-device mesh unless it is partitioned by hand.  Input specs are
+    the example's shardings; outputs that pass an input through keep its
+    spec, and every other output is (lane, slot)-batched like the slot's
+    received grid ``y``.
+    """
+    in_specs = {k: v.sharding.spec for k, v in example.items()}
+    batched = P(*tuple(in_specs["y"])[:2])
+    out_specs = {
+        k: in_specs.get(k, batched)
+        for k in jax.eval_shape(fn, example)
+    }
+    # check_vma=False: pallas_call output shapes carry no per-axis
+    # variance annotation, and nothing here is replicated to check
+    return shard_map(fn, mesh=mesh, in_specs=(in_specs,),
+                     out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -490,6 +528,10 @@ class ExecRegistry:
     def keys(self) -> list:
         return list(self._entries)
 
+    def items(self) -> list:
+        """(key, compiled executable) for every resident entry."""
+        return [(k, e.compiled) for k, e in self._entries.items()]
+
     # -- acquisition ------------------------------------------------------
     def acquire(self, key: ExecKey, fn: Callable, example,
                 *, stats: Optional[ExecStats] = None):
@@ -549,13 +591,19 @@ class ExecRegistry:
 
         ``lanes == 0`` compiles the single-cell step (``pipeline._apply``
         over a stacked batch); ``lanes > 0`` the mesh step
-        (``vmap(pipeline._apply)`` over staged (lanes, batch, ...) arrays).
+        (``vmap(pipeline._apply)`` over staged (lanes, batch, ...)
+        arrays, shard-mapped over their mesh by :func:`mesh_step`).
         """
+        mesh = _example_mesh(example) if lanes else None
         key = exec_key_for(
             pipeline, batch, lanes=lanes, donate=donate,
-            schema=slot_schema(example),
+            schema=slot_schema(example), mesh=mesh,
         )
-        fn = jax.vmap(pipeline._apply) if lanes else pipeline._apply
+        fn = pipeline._apply
+        if lanes:
+            fn = jax.vmap(fn)
+            if mesh is not None:
+                fn = mesh_step(fn, example, mesh)
         return self.acquire(key, fn, example, stats=stats)
 
     # -- reporting --------------------------------------------------------
@@ -575,9 +623,9 @@ _DEFAULT: Optional[ExecRegistry] = None
 def get_registry() -> ExecRegistry:
     """The process-wide default registry (shared across every engine).
 
-    Re-created when the env-resolved cache dir changes, mirroring
-    :func:`repro.kernels.tune.get_cache` — tests that point
-    ``REPRO_XLA_CACHE`` at a tmp dir get a fresh registry on that dir.
+    Re-created when the env-resolved cache dir changes — tests that point
+    ``JAX_COMPILATION_CACHE_DIR`` at a tmp dir get a fresh registry on
+    that dir.
     """
     global _DEFAULT
     if _DEFAULT is None or _DEFAULT.cache_dir != default_cache_dir():

@@ -300,7 +300,7 @@ def test_autotune_ldpc_persists_winner(tmp_path, monkeypatch):
     tune.set_cache_path(str(tmp_path / "tune.json"))
     try:
         choice = tune.autotune_ldpc(8, CODE, max_iters=4, iters=1)
-        assert 8 % choice[0] == 0
+        assert choice[0] % ldpc.LANE == 0
         key = tune.cache_key(
             "ldpc_decode", (CODE.k_b, CODE.m_b, CODE.z, 4)
         )

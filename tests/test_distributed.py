@@ -21,12 +21,8 @@ from repro.models import get_model
 from repro.train import step as step_lib
 from repro.data import TokenStream
 
-_AxisType = getattr(jax.sharding, "AxisType", None)
-if _AxisType is not None:
-    mesh = jax.make_mesh((4, 2), ("data", "model"),
-                         axis_types=(_AxisType.Auto,) * 2)
-else:  # older jax: meshes are implicitly auto
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke_config("llama3-8b")
 model = get_model(cfg)
 tc = TrainConfig(learning_rate=1e-3, microbatches=1)

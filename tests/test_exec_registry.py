@@ -92,6 +92,14 @@ def test_exec_key_distinguishes_shape_and_schema():
     assert exec_key_for(p, 4, lanes=2) != base
     assert exec_key_for(p, 4, donate=True) != base
     assert exec_key_for(p, 4, schema="tx_bits+rx_grid") != base
+    # one lane bucket on two meshes: two executables
+    dev = np.asarray(jax.devices()[:1])
+    m11 = jax.sharding.Mesh(dev.reshape(1, 1), ("cell", "batch"))
+    m1 = jax.sharding.Mesh(dev.reshape(1), ("cell",))
+    assert exec_key_for(p, 4, lanes=2, mesh=m11) \
+        != exec_key_for(p, 4, lanes=2, mesh=m1)
+    assert exec_key_for(p, 4, lanes=2, mesh=m11) \
+        != exec_key_for(p, 4, lanes=2)
     # same everything -> equal and hashable-stable
     assert exec_key_for(p, 4) == base
     assert hash(exec_key_for(p, 4)) == hash(base)
@@ -253,10 +261,14 @@ def test_cache_detaches_after_builds(tmp_path):
 def test_get_registry_follows_env(tmp_path, monkeypatch):
     import repro.serve.exec_registry as er
 
-    monkeypatch.setenv("REPRO_XLA_CACHE", str(tmp_path / "xla-env"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla-env"))
     monkeypatch.setattr(er, "_DEFAULT", None)
     reg = get_registry()
     assert reg.cache_dir == str(tmp_path / "xla-env")
     assert get_registry() is reg  # stable while the env holds
-    monkeypatch.setenv("REPRO_XLA_CACHE", str(tmp_path / "xla-env2"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla-env2"))
     assert get_registry() is not reg  # dir change -> fresh registry
+    # unset: one fixed directory inside the checkout
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert get_registry().cache_dir == os.path.join(root, ".cache", "jax")

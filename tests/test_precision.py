@@ -202,15 +202,11 @@ def test_quantized_pipeline_end_to_end():
 def test_cache_key_distinguishes_one_byte_dtypes():
     shape = (256, 256, 256)
     k_int8 = tune.cache_key("te_gemm", shape, quant.dtype_name(jnp.int8))
-    name_fp8 = (quant.dtype_name(quant.FP8_DTYPE) if quant.HAS_FP8
-                else "float8_e4m3fn")
-    k_fp8 = tune.cache_key("te_gemm", shape, name_fp8)
+    k_fp8 = tune.cache_key("te_gemm", shape, quant.dtype_name(quant.FP8_DTYPE))
     assert k_int8 != k_fp8
 
 
 def test_pick_block_shape_keeps_one_byte_tunings_apart(tmp_path):
-    if not quant.HAS_FP8:
-        pytest.skip("no float8_e4m3fn in this jax build")
     tune.set_cache_path(str(tmp_path / "tune.json"))
     try:
         shape = (512, 512, 512)

@@ -20,9 +20,10 @@ def _compile(fn, *specs):
 
 
 def _cost(compiled) -> dict:
-    """cost_analysis() returns [dict] on older jax, dict on newer."""
+    """cost_analysis() is one dict of counters."""
     ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
+    assert isinstance(ca, dict), type(ca)
+    return ca
 
 
 def test_xla_cost_analysis_undercounts_loops():

@@ -270,6 +270,20 @@ def test_tune_cache_roundtrip(tmp_path):
     assert tune.TuneCache(path).lookup(key) == (128, 256, 128)
 
 
+def test_tune_cache_in_memory_without_path(tmp_path, monkeypatch):
+    """With no path named, tuned winners live in the process only: nothing
+    is read from or written to a default location."""
+    monkeypatch.delenv("REPRO_TUNE_CACHE", raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    cache = tune.TuneCache()
+    assert cache.path is None
+    key = tune.cache_key("te_gemm", (256, 256, 384), "b2", backend="cpu")
+    cache.store(key, (128, 256, 128), us=42.0)
+    assert cache.lookup(key) == (128, 256, 128)
+    assert tune.TuneCache().lookup(key) is None
+    assert list(tmp_path.iterdir()) == []
+
+
 def test_tune_cache_tolerates_corrupt_file(tmp_path):
     path = tmp_path / "tune.json"
     path.write_text("{not json")
