@@ -1,0 +1,7 @@
+"""Summed device time of the fused joint-MMSE detect+demap kernel
+(``rx_detect_demap``) over slots served."""
+from metrics.kernel_time import us_per_slot
+
+
+def read(run):
+    return us_per_slot(run, "rx_detect_demap")
