@@ -86,7 +86,9 @@ class ReceiverPipeline:
     def _apply(self, slot: dict) -> dict:
         state = dict(slot)
         for st in self.stages:
-            state = st.apply(state)
+            # names the stage's ops (``op_name``) in the compiled program
+            with jax.named_scope(st.name):
+                state = st.apply(state)
         return state
 
     def run(self, slot: dict) -> dict:

@@ -74,6 +74,7 @@ from repro.serve.runtime import (
     cell_rng, first_steady, make_traffic, occupancy_energy, resolve_ladder,
     stack_slots,
 )
+from repro.serve.trace import span, step_window, wait
 
 
 @dataclasses.dataclass(frozen=True)
@@ -449,7 +450,7 @@ class CellMeshEngine:
                 state = step(staged)  # async dispatch
                 staged = (self._stage(plan[i + 1])
                           if i + 1 < len(plan) else None)
-                state = jax.block_until_ready(state)
+                state = wait(state)
                 dt = time.perf_counter() - t0
                 t_group += dt
                 self.step_times.append(dt)
@@ -1017,20 +1018,22 @@ class MeshSlotScheduler:
         bucket."""
         if bucket is None:
             bucket = self._bucket(len(lanes))
-        per_lane = [
-            stack_slots(lane.slots, lane.pad, xp=np) for lane in lanes
-        ]
-        per_lane += [per_lane[0]] * (bucket - len(lanes))
-        stacked = {
-            k: np.stack([np.asarray(pl[k]) for pl in per_lane], axis=0)
-            for k in per_lane[0]
-        }
-        shardings = shd.cell_slot_shardings(
-            stacked, self.mesh, batched_keys=BATCHED_KEYS
-        )
-        return {
-            k: jax.device_put(v, shardings[k]) for k, v in stacked.items()
-        }
+        with span("serve.stage", lanes=len(lanes), bucket=bucket):
+            per_lane = [
+                stack_slots(lane.slots, lane.pad, xp=np) for lane in lanes
+            ]
+            per_lane += [per_lane[0]] * (bucket - len(lanes))
+            stacked = {
+                k: np.stack([np.asarray(pl[k]) for pl in per_lane], axis=0)
+                for k in per_lane[0]
+            }
+            shardings = shd.cell_slot_shardings(
+                stacked, self.mesh, batched_keys=BATCHED_KEYS
+            )
+            return {
+                k: jax.device_put(v, shardings[k])
+                for k, v in stacked.items()
+            }
 
     # -- the lockstep TTI loop --------------------------------------------
     #
@@ -1051,20 +1054,22 @@ class MeshSlotScheduler:
     def _plan_tick(self) -> list:
         """Plan every cell's batches, bucketed per (ladder group, rung)."""
         work: dict[tuple, list[_ClosedLane]] = {}
-        for gi, g in enumerate(self.groups):
-            for ci in g.cell_idxs:
-                if not self._cell_plannable(ci):
-                    continue
-                loop = self.loops[ci]
-                for mcs, pairs in loop.plan_batches():
-                    slots = [
-                        loop.make_slot(u, job, mcs) for u, job in pairs
-                    ]
-                    loop.n_batches += 1
-                    work.setdefault((gi, mcs), []).append(_ClosedLane(
-                        cell_idx=ci, pairs=pairs, slots=slots,
-                        pad=self.batch_size - len(pairs),
-                    ))
+        with span("serve.plan") as sp:
+            for gi, g in enumerate(self.groups):
+                for ci in g.cell_idxs:
+                    if not self._cell_plannable(ci):
+                        continue
+                    loop = self.loops[ci]
+                    for mcs, pairs in loop.plan_batches():
+                        slots = [
+                            loop.make_slot(u, job, mcs) for u, job in pairs
+                        ]
+                        loop.n_batches += 1
+                        work.setdefault((gi, mcs), []).append(_ClosedLane(
+                            cell_idx=ci, pairs=pairs, slots=slots,
+                            pad=self.batch_size - len(pairs),
+                        ))
+            sp.set_metadata(batches=sum(len(v) for v in work.values()))
         return sorted(work.items())
 
     def _serve_items(self, items: list, stats: list[TickStats]) -> None:
@@ -1092,11 +1097,10 @@ class MeshSlotScheduler:
         """
         bucket = self._bucket(len(lanes))
         step = self._step_for(gi, mcs, bucket, staged)
-        t0 = time.perf_counter()
-        state = step(staged)  # async dispatch
-        nxt = prefetch() if prefetch is not None else None
-        state = jax.block_until_ready(state)
-        self.wall_s += time.perf_counter() - t0
+        with step_window(self, lanes=len(lanes), bucket=bucket, mcs=mcs):
+            state = step(staged)  # async dispatch
+            nxt = prefetch() if prefetch is not None else None
+            state = wait(state)
         self.n_steps += 1
         self.n_real_lanes += len(lanes)
         self.n_filler_lanes += bucket - len(lanes)
@@ -1153,34 +1157,41 @@ class MeshSlotScheduler:
 
     def tick(self) -> list[TickStats]:
         """Advance every cell one TTI in lockstep."""
-        self._begin_tick()
-        stats = [TickStats(tick=loop.now) for loop in self.loops]
-        for loop, st in zip(self.loops, stats):
-            loop.arrive(st)
-        self._rebalance()
-        items = self._plan_tick()
-        n0, w0 = self.n_steps, self.wall_s
-        self._serve_items(items, stats)
-        # first vs steady-state latency: only ticks that served a step
-        if self.n_steps > n0:
-            self.tick_times.append(self.wall_s - w0)
-        for loop, st in zip(self.loops, stats):
-            loop.end_tick(st)
-        self._end_tick_hook(stats)
-        self.now += 1
+        with span("serve.tick") as sp:
+            self._begin_tick()
+            stats = [TickStats(tick=loop.now) for loop in self.loops]
+            with span("serve.arrive"):
+                for loop, st in zip(self.loops, stats):
+                    loop.arrive(st)
+            with span("serve.rebalance"):
+                self._rebalance()
+            items = self._plan_tick()
+            sp.set_metadata(slots=sum(
+                len(lane.pairs) for _, lanes in items for lane in lanes))
+            n0, w0 = self.n_steps, self.wall_s
+            self._serve_items(items, stats)
+            # first vs steady-state latency: only ticks that served a step
+            if self.n_steps > n0:
+                self.tick_times.append(self.wall_s - w0)
+            with span("serve.end_tick"):
+                for loop, st in zip(self.loops, stats):
+                    loop.end_tick(st)
+                self._end_tick_hook(stats)
+            self.now += 1
         return stats
 
     def _feedback(self, lanes: list[_ClosedLane], mcs: int, state: dict,
                   stats: list[TickStats]) -> None:
-        crc_ok = np.asarray(state["crc_ok"])  # (L, B, C)
-        cw_llr = np.asarray(state["cw_llr"])  # (L, B, C, n_mother)
-        for li, lane in enumerate(lanes):
-            loop = self.loops[lane.cell_idx]
-            for j, (u, job) in enumerate(lane.pairs):
-                loop.serve_feedback(
-                    u, job, mcs, crc_ok[li, j].astype(bool),
-                    cw_llr[li, j : j + 1], stats[lane.cell_idx],
-                )
+        with span("serve.feedback", lanes=len(lanes)):
+            crc_ok = np.asarray(state["crc_ok"])  # (L, B, C)
+            cw_llr = np.asarray(state["cw_llr"])  # (L, B, C, n_mother)
+            for li, lane in enumerate(lanes):
+                loop = self.loops[lane.cell_idx]
+                for j, (u, job) in enumerate(lane.pairs):
+                    loop.serve_feedback(
+                        u, job, mcs, crc_ok[li, j].astype(bool),
+                        cw_llr[li, j : j + 1], stats[lane.cell_idx],
+                    )
 
     def run(self, n_ticks: int) -> MeshClosedLoopReport:
         for _ in range(n_ticks):

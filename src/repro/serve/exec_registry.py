@@ -66,6 +66,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.serve.trace import span
+
 _ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 # src/repro/serve/exec_registry.py -> the checkout root
 _CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
@@ -555,25 +557,30 @@ class ExecRegistry:
         jit_kw = {"donate_argnums": 0} if key.donate else {}
         h0, m0 = _EVENTS["hits"], _EVENTS["misses"]
         t0 = time.perf_counter()
-        # the on-disk cache is attached only for the registry's own build
-        # window: process-wide attachment drags unrelated jits (donated
-        # train steps) through the serializer, which corrupts buffer
-        # lifetimes on CPU — see enable_persistent_cache's docstring
-        if self.persistent:
-            enable_persistent_cache(self.cache_dir)
-        try:
-            compiled = jax.jit(fn, **jit_kw).lower(example).compile()
-        finally:
+        with span("serve.acquire") as sp:
+            # the on-disk cache is attached only for the registry's own
+            # build window: process-wide attachment drags unrelated jits
+            # (donated train steps) through the serializer, which
+            # corrupts buffer lifetimes on CPU — see
+            # enable_persistent_cache's docstring
             if self.persistent:
-                disable_persistent_cache()
-        dt = time.perf_counter() - t0
-        del h0  # hit events corroborate but don't decide attribution
-        misses = _EVENTS["misses"] - m0
-        # a true XLA compile always reads the persistent cache first and
-        # misses; zero misses therefore means *some* cache satisfied the
-        # build (the on-disk cache, or jax's in-process executable cache
-        # when this computation already compiled this process)
-        from_cache = self.persistent and misses == 0
+                enable_persistent_cache(self.cache_dir)
+            try:
+                compiled = jax.jit(fn, **jit_kw).lower(example).compile()
+            finally:
+                if self.persistent:
+                    disable_persistent_cache()
+            dt = time.perf_counter() - t0
+            del h0  # hit events corroborate but don't decide attribution
+            misses = _EVENTS["misses"] - m0
+            # a true XLA compile always reads the persistent cache first
+            # and misses; zero misses therefore means *some* cache
+            # satisfied the build (the on-disk cache, or jax's in-process
+            # executable cache when this computation already compiled
+            # this process)
+            from_cache = self.persistent and misses == 0
+            sp.set_metadata(compiled=int(not from_cache),
+                            cache_hit=int(from_cache))
         self.stats.add(dt, not from_cache, from_cache)
         if stats is not None:
             stats.add(dt, not from_cache, from_cache)

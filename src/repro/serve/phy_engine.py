@@ -29,6 +29,7 @@ from repro.serve.runtime import (  # noqa: F401  (re-exported API)
     build_serve_report,
     make_traffic,
 )
+from repro.serve.trace import span
 
 
 class PhyServeEngine:
@@ -116,10 +117,12 @@ class PhyServeEngine:
         self._queue = []
         runner = self._make_runner()
         n_batches = runner.drain(reqs, warmup=warmup)
-        return build_serve_report(
-            self.pipeline, self.pipeline.scenario,
-            [r.metrics for r in reqs],
-            n_slots=len(reqs), n_batches=n_batches,
-            batch_size=self.batch_size, wall_s=runner.wall_s,
-            exec_stats=runner.exec_stats, batch_times=runner.batch_times,
-        )
+        with span("serve.report"):
+            return build_serve_report(
+                self.pipeline, self.pipeline.scenario,
+                [r.metrics for r in reqs],
+                n_slots=len(reqs), n_batches=n_batches,
+                batch_size=self.batch_size, wall_s=runner.wall_s,
+                exec_stats=runner.exec_stats,
+                batch_times=runner.batch_times,
+            )

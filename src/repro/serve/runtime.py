@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Optional, Union
 
 import jax
@@ -62,6 +61,7 @@ from repro.phy import link as _link
 from repro.serve.exec_registry import (
     ExecStats, get_registry, slot_schema, template_batch,
 )
+from repro.serve.trace import span, step_window, wait
 
 # slot keys with a leading per-user batch axis; everything else is
 # scenario-static side info shared by every user.  "info_bits" only
@@ -409,11 +409,9 @@ class BatchRunner:
         """Run one stacked batch inside the timed window.  Overridable:
         :class:`repro.serve.supervisor.SupervisedBatchRunner` interposes
         retry and non-finite-guard handling here."""
-        t0 = time.perf_counter()
-        state = jax.block_until_ready(self._step(batch))
-        dt = time.perf_counter() - t0
-        self.wall_s += dt
-        self.batch_times.append(dt)
+        with step_window(self) as window:
+            state = wait(self._step(batch))
+        self.batch_times.append(window["dt"])
         return state
 
     def run_batch(self, reqs: list) -> dict:
@@ -422,18 +420,21 @@ class BatchRunner:
         Marks each request done with its per-slot metrics; padded tail
         results are discarded.
         """
-        batch = stack_slots(
-            [r.slot for r in reqs], self.batch_size - len(reqs)
-        )
-        state = self._execute(batch)
-        self.n_batches += 1
-        metrics = _link.slot_metrics(
-            state, self.pipeline.scenario, per_slot=True
-        )
-        metrics = {k: np.asarray(v) for k, v in metrics.items()}
-        for j, r in enumerate(reqs):
-            r.metrics = {k: float(v[j]) for k, v in metrics.items()}
-            r.done = True
+        with span("serve.batch", slots=len(reqs)):
+            with span("serve.stack"):
+                batch = stack_slots(
+                    [r.slot for r in reqs], self.batch_size - len(reqs)
+                )
+            state = self._execute(batch)
+            self.n_batches += 1
+            with span("serve.slot_metrics"):
+                metrics = _link.slot_metrics(
+                    state, self.pipeline.scenario, per_slot=True
+                )
+                metrics = {k: np.asarray(v) for k, v in metrics.items()}
+            for j, r in enumerate(reqs):
+                r.metrics = {k: float(v[j]) for k, v in metrics.items()}
+                r.done = True
         return state
 
     def drain(self, reqs: list, warmup: bool = True) -> int:
@@ -778,29 +779,30 @@ class CellLoop:
         """
         from repro.phy import coding
 
-        if job.harq is None:
-            scn = self.rungs[mcs]
-            n_cw = coding.codewords_per_slot(scn)
-            slot = coding.make_coded_slot(
-                self.next_key(), self._tx_scenario(scn, user), 1, rv=0
-            )
-            job.harq = HarqProcess(
-                mcs=mcs,
-                info=np.asarray(slot["info_bits"]),
-                prior=np.zeros(
-                    (1, n_cw, scn.code.n_mother), np.float32
-                ),
-                acked=np.zeros(n_cw, bool),
-            )
-        else:
-            h = job.harq
-            scn = self.rungs[h.mcs]  # retx pins the MCS of the first tx
-            slot = coding.make_coded_slot(
-                self.next_key(), self._tx_scenario(scn, user), 1,
-                rv=h.rv, info=h.info,
-            )
-        slot["prior_llr"] = job.harq.prior
-        return slot
+        with span("serve.make_slot", retx=int(job.harq is not None)):
+            if job.harq is None:
+                scn = self.rungs[mcs]
+                n_cw = coding.codewords_per_slot(scn)
+                slot = coding.make_coded_slot(
+                    self.next_key(), self._tx_scenario(scn, user), 1, rv=0
+                )
+                job.harq = HarqProcess(
+                    mcs=mcs,
+                    info=np.asarray(slot["info_bits"]),
+                    prior=np.zeros(
+                        (1, n_cw, scn.code.n_mother), np.float32
+                    ),
+                    acked=np.zeros(n_cw, bool),
+                )
+            else:
+                h = job.harq
+                scn = self.rungs[h.mcs]  # retx pins the MCS of the first tx
+                slot = coding.make_coded_slot(
+                    self.next_key(), self._tx_scenario(scn, user), 1,
+                    rv=h.rv, info=h.info,
+                )
+            slot["prior_llr"] = job.harq.prior
+            return slot
 
     def _tx_scenario(self, scn, user: UserState):
         """The per-transmission scenario: the rung at the user's SNR, plus

@@ -78,6 +78,7 @@ from repro.serve.faults import FaultInjector, FaultPlan, InjectedFault
 from repro.serve.runtime import (
     BatchRunner, CellLoop, HarqProcess, TickStats, UserState, _Job,
 )
+from repro.serve.trace import step_window, wait
 
 __all__ = [
     "SupervisedBatchRunner", "Supervisor",
@@ -247,15 +248,12 @@ class SupervisedBatchRunner(BatchRunner):
     def _execute(self, batch: dict) -> dict:
         state = None
         for attempt in range(self.max_retries + 1):
-            t0 = time.perf_counter()
             try:
-                state = jax.block_until_ready(self._step(batch))
-                dt = time.perf_counter() - t0
-                self.wall_s += dt
-                self.batch_times.append(dt)
+                with step_window(self) as window:
+                    state = wait(self._step(batch))
+                self.batch_times.append(window["dt"])
                 break
             except InjectedFault:
-                self.wall_s += time.perf_counter() - t0
                 if attempt >= self.max_retries:
                     raise
                 self.retries += 1
@@ -264,9 +262,8 @@ class SupervisedBatchRunner(BatchRunner):
         if not self._guard_ok(state):
             self.degraded_batches += 1
             ref = self._ref_exec(batch)
-            t0 = time.perf_counter()
-            state = jax.block_until_ready(ref(batch))
-            self.wall_s += time.perf_counter() - t0
+            with step_window(self):
+                state = wait(ref(batch))
         return state
 
 
@@ -555,27 +552,26 @@ class Supervisor(MeshSlotScheduler):
 
         nxt, prefetched = None, False
         state = None
+        counts = dict(lanes=len(lanes), bucket=bucket, mcs=mcs)
         for attempt in range(self.max_step_retries + 1):
             ev = self.injector.step_error(self.now, seq)
-            t0 = time.perf_counter()
             try:
-                if ev is not None:
-                    raise InjectedFault(
-                        f"injected step error at tick {self.now} "
-                        f"bucket {seq} (attempt {attempt})"
-                    )
-                out = step(staged)  # async dispatch
-                if not prefetched:
-                    nxt = prefetch() if prefetch is not None else None
-                    prefetched = True
-                if straggle > 0.0:
-                    time.sleep(straggle)
-                    straggle = 0.0
-                state = jax.block_until_ready(out)
-                self.wall_s += time.perf_counter() - t0
+                with step_window(self, **counts):
+                    if ev is not None:
+                        raise InjectedFault(
+                            f"injected step error at tick {self.now} "
+                            f"bucket {seq} (attempt {attempt})"
+                        )
+                    out = step(staged)  # async dispatch
+                    if not prefetched:
+                        nxt = prefetch() if prefetch is not None else None
+                        prefetched = True
+                    if straggle > 0.0:
+                        time.sleep(straggle)
+                        straggle = 0.0
+                    state = wait(out)
                 break
             except Exception:
-                self.wall_s += time.perf_counter() - t0
                 if attempt >= self.max_step_retries:
                     break  # retries exhausted: quarantine the bucket
                 self.step_retries += 1
@@ -613,9 +609,8 @@ class Supervisor(MeshSlotScheduler):
                 self._charge_fault(lanes[li].cell_idx)
             clean = self._stage(lanes)
             ref = self._ref_step(gi, mcs, bucket, clean)
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(ref(clean))
-            self.wall_s += time.perf_counter() - t0
+            with step_window(self, **counts):
+                out = wait(ref(clean))
             rcrc = np.asarray(out["crc_ok"])
             rllr = np.asarray(out["cw_llr"])
             for li in bad:
