@@ -261,13 +261,18 @@ def rv_offset(code: CodeConfig, rv):
     return ((rv % N_RV) * code.n_b) // N_RV * code.z
 
 
-def rate_match(code: CodeConfig, cw: jax.Array, rv: int = 0) -> jax.Array:
+def rate_match(code: CodeConfig, cw: jax.Array, rv=0) -> jax.Array:
     """codeword (..., n_mother) -> transmitted bits (..., e_bits): the
     circular-buffer window starting at :func:`rv_offset`.  RV0 is the
-    systematic part + leading parity blocks (tail punctured)."""
-    off = int(rv_offset(code, rv))
-    if off == 0:
-        return cw[..., : code.e_bits]
+    systematic part + leading parity blocks (tail punctured).  ``rv`` may
+    be a python int (static window) or a traced int scalar (one compiled
+    generator serves every retransmission's RV)."""
+    if isinstance(rv, (int, np.integer)):
+        off = int(rv_offset(code, rv))
+        if off == 0:
+            return cw[..., : code.e_bits]
+    else:
+        off = rv_offset(code, jnp.asarray(rv, jnp.int32))
     return jnp.roll(cw, -off, axis=-1)[..., : code.e_bits]
 
 
@@ -318,7 +323,9 @@ def _data_re_index(grid: ofdm.GridConfig):
     laid onto the grid and gathered back."""
     union = ofdm.link_pilot_masks_np(grid).any(axis=0)
     sym, sc = np.nonzero(~union)
-    return jnp.asarray(sym), jnp.asarray(sc)
+    # numpy, not jnp: a jnp array made while a trace is open would be
+    # cached as that trace's tracer and leak into the next one
+    return sym.astype(np.int32), sc.astype(np.int32)
 
 
 def codewords_per_slot(scenario) -> int:
@@ -356,6 +363,10 @@ def make_coded_slot(key: jax.Array, scenario, batch: int,
     circular buffer; a non-None ``rv`` also stamps an ``rv`` array (B,)
     into the slot so the decode stage de-rate-matches per slot inside
     one compiled batch.
+
+    Traceable: under ``jit`` ``rv`` and ``scenario.snr_db`` may be traced
+    scalars (:class:`repro.serve.runtime.SlotGenerator` compiles this
+    body once per scenario shape).
     """
     code, g = scenario.code, scenario.grid
     nb = scenario.modem.bits_per_symbol
@@ -373,7 +384,7 @@ def make_coded_slot(key: jax.Array, scenario, batch: int,
         info = jnp.asarray(info, jnp.int32)
         assert info.shape == (batch, c, code.k_info), info.shape
     tx = rate_match(code, encode(code, crc_attach(info, code.crc_bits)),
-                    rv=rv or 0)
+                    rv=0 if rv is None else rv)
     flat = tx.reshape(batch, c * code.e_bits)
     n_fill = scenario.data_bits_per_slot - c * code.e_bits
     if n_fill:
@@ -396,7 +407,7 @@ def make_coded_slot(key: jax.Array, scenario, batch: int,
     )
     slot["info_bits"] = info
     if rv is not None:
-        slot["rv"] = jnp.full((batch,), int(rv), jnp.int32)
+        slot["rv"] = jnp.full((batch,), rv, jnp.int32)
     return slot
 
 

@@ -70,9 +70,9 @@ from repro.serve.exec_registry import (
 )
 from repro.serve.runtime import (
     BATCHED_KEYS, CellLoop, ClosedLoopReport, JobCounter, PhyServeReport,
-    SlotLedger, SlotRequest, TTI_S, TickStats, build_serve_report,
-    cell_rng, first_steady, make_traffic, occupancy_energy, resolve_ladder,
-    stack_slots,
+    SlotGenerator, SlotLedger, SlotRequest, TTI_S, TickStats,
+    build_serve_report, cell_rng, first_steady, make_traffic,
+    occupancy_energy, resolve_ladder, stack_slots,
 )
 from repro.serve.trace import span, step_window, wait
 
@@ -826,6 +826,10 @@ class MeshSlotScheduler:
             for i in idxs:
                 self._group_of[i] = g
 
+        self.registry = registry if registry is not None else get_registry()
+        self.exec_stats = ExecStats()
+        self.slot_gen = SlotGenerator(registry=self.registry,
+                                      stats=self.exec_stats)
         self._uid_bases: list[int] = []
         uid_base = 0
         for spec in self.specs:
@@ -853,8 +857,6 @@ class MeshSlotScheduler:
                     f"bucket {b} of {self.bucket_policy!r} is not a "
                     f"multiple of the mesh cell axis ({self._min_lanes})"
                 )
-        self.registry = registry if registry is not None else get_registry()
-        self.exec_stats = ExecStats()
         self.tick_times: list[float] = []
         self.wall_s = 0.0
         self.n_steps = 0
@@ -910,6 +912,7 @@ class MeshSlotScheduler:
             snr_db=spec.snr_db, snr_spread_db=spec.snr_spread_db,
             interferer_db=self._coupled_interferers(i),
             uid_base=self._uid_bases[i], job_ids=self.job_counter,
+            slot_gen=self.slot_gen,
         )
 
     def _coupled_interferers(self, i: int) -> tuple:
@@ -1126,7 +1129,8 @@ class MeshSlotScheduler:
 
     def _prebuild(self) -> None:
         """AOT-populate every (group, rung) step at the group's base lane
-        bucket before the first TTI.  Templates ride the exact staging
+        bucket, and every cell's slot-generator executables, before the
+        first TTI.  Templates ride the exact staging
         path dispatch uses; with a warm persistent cache this is all
         cache hits, so a fresh process reaches its first served TTI with
         zero new XLA compilations.  Buckets beyond the base (bursty
@@ -1150,6 +1154,8 @@ class MeshSlotScheduler:
                 )
                 staged = self._stage([lane], bucket=spec.lanes)
                 self._step_for(gi, mcs, spec.lanes, staged)
+        for loop in self.loops:
+            loop.prebuild_slots()
 
     def _end_tick_hook(self, stats: list[TickStats]) -> None:
         """Hook after every cell's end_tick (supervisor: periodic

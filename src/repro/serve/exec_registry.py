@@ -41,6 +41,8 @@ This module centralizes all of it:
   avals); structure is everything, so templates ride the exact slot
   builders (:func:`repro.phy.coding.make_coded_slot`,
   :meth:`repro.phy.scenarios.LinkScenario.make_batch`) the runtime uses.
+  The closed loop's compiled slot generators
+  (:class:`repro.serve.runtime.SlotGenerator`) are registry entries too.
 
 The process-wide default registry (:func:`get_registry`) is shared by
 every engine in the process — two schedulers serving the same ladder at
@@ -339,8 +341,9 @@ def template_slot(scenario, *, harq: bool = False) -> dict:
     irrelevant to compilation — only avals reach the lowered HLO).
 
     ``harq=True`` builds the closed-loop schema: a coded slot at RV 0
-    with the zeroed combining-LLR prior riding along, exactly as
-    :meth:`repro.serve.runtime.CellLoop.make_slot` stages it.
+    with the zeroed combining-LLR prior riding along — the keys, shapes
+    and dtypes :meth:`repro.serve.runtime.CellLoop.make_slot` stages
+    from its compiled :class:`~repro.serve.runtime.SlotGenerator`.
     """
     key = jax.random.PRNGKey(0)
     if not harq:

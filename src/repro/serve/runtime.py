@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import hashlib
 from typing import Optional, Union
 
 import jax
@@ -59,7 +60,7 @@ import numpy as np
 
 from repro.phy import link as _link
 from repro.serve.exec_registry import (
-    ExecStats, get_registry, slot_schema, template_batch,
+    ExecKey, ExecStats, get_registry, slot_schema, template_batch,
 )
 from repro.serve.trace import span, step_window, wait
 
@@ -245,9 +246,15 @@ def cell_rng(seed: int, cell: int = 0) -> np.random.Generator:
     )
 
 
+def rng_seed(rng: np.random.Generator) -> int:
+    """Draw the integer seed of a fresh jax PRNG key from a numpy
+    Generator stream."""
+    return int(rng.integers(0, 2**31 - 1))
+
+
 def rng_key(rng: np.random.Generator) -> jax.Array:
     """Draw a fresh jax PRNG key from a numpy Generator stream."""
-    return jax.random.PRNGKey(int(rng.integers(0, 2**31 - 1)))
+    return jax.random.PRNGKey(rng_seed(rng))
 
 
 def make_traffic(scenario, rng, n: int) -> list:
@@ -661,8 +668,107 @@ def occupancy_energy(occupancy, pipelines):
     )
 
 
+def _coded_slot_fn(scenario, retx: bool):
+    """:func:`repro.phy.coding.make_coded_slot` at batch 1 as a function
+    of one transmission's values: ``seed`` (the integer behind the slot's
+    PRNG key), ``snr_db`` and, for a retransmission, ``rv`` and the pinned
+    transport blocks ``info``.  Everything else comes from ``scenario``."""
+    from repro.phy import coding
+
+    def gen(args: dict) -> dict:
+        key = jax.random.PRNGKey(args["seed"])
+        scn = scenario.replace(snr_db=args["snr_db"])
+        if retx:
+            return coding.make_coded_slot(key, scn, 1, rv=args["rv"],
+                                          info=args["info"])
+        return coding.make_coded_slot(key, scn, 1, rv=0)
+
+    return gen
+
+
+class SlotGenerator:
+    """Closed-loop slot generation as prebuilt compiled executables.
+
+    Built eagerly, one coded slot is ~300 small device dispatches (CRC,
+    QC-LDPC encode, rate matching, grid mapping, channel, noise, IFFT);
+    here it is one call of an AOT executable acquired through the
+    :class:`~repro.serve.exec_registry.ExecRegistry`.  Executables are
+    keyed by what fixes the computation — grid, modem, code, interferer
+    powers, Doppler, per-stream powers — and by the kind of transmission:
+    new data (RV 0, fresh transport blocks) or a retransmission (the RV
+    and the pinned blocks as arguments).  Key seed and SNR are arguments,
+    so users at any SNR share one executable: at most two per rung.
+    """
+
+    def __init__(self, *, registry=None, stats: Optional[ExecStats] = None):
+        self.registry = registry if registry is not None else get_registry()
+        self.stats = stats if stats is not None else ExecStats()
+        self._execs: dict = {}  # (shape key, retx) -> compiled generator
+
+    @staticmethod
+    def _shape(scenario) -> tuple:
+        return (scenario.grid, scenario.modulation, scenario.code,
+                tuple(scenario.interferer_db), scenario.doppler_rho,
+                scenario.user_power_db)
+
+    def _exec(self, scenario, retx: bool):
+        """The resident executable, acquiring it first if absent;
+        returns ``(executable, was_resident)``."""
+        shape = self._shape(scenario)
+        ex = self._execs.get((shape, retx))
+        if ex is not None:
+            return ex, True
+        from repro.phy import coding
+
+        code = scenario.code
+        example = {"seed": np.int32(0),
+                   "snr_db": np.float32(scenario.snr_db)}
+        if retx:
+            example["rv"] = np.int32(1)
+            example["info"] = np.zeros(
+                (1, coding.codewords_per_slot(scenario), code.k_info),
+                np.int32,
+            )
+        key = ExecKey(
+            scenario=f"coded_slot:{code.name}:{scenario.modulation}",
+            receiver="slot_gen", precision="fp32", batch=1, lanes=0,
+            backend=jax.default_backend(),
+            variant=hashlib.blake2b(
+                repr(shape).encode(), digest_size=8
+            ).hexdigest(),
+            schema="retx" if retx else "new",
+        )
+        ex = self.registry.acquire(
+            key, _coded_slot_fn(scenario, retx), example, stats=self.stats
+        )
+        self._execs[(shape, retx)] = ex
+        return ex, False
+
+    def prebuild(self, scenario, max_retx: int) -> None:
+        """Acquire every executable transmissions of ``scenario`` use."""
+        self._exec(scenario, False)
+        if max_retx > 0:
+            self._exec(scenario, True)
+
+    def __call__(self, scenario, seed: int, *, rv: int = 0,
+                 info: Optional[np.ndarray] = None) -> tuple:
+        """One batch-1 coded slot of ``scenario`` (its ``snr_db`` included)
+        from key seed ``seed``: new data when ``info`` is None, else a
+        retransmission of ``info`` at ``rv``.  Returns ``(slot,
+        was_prebuilt)``; the slot has the keys, shapes and dtypes of
+        :func:`~repro.phy.coding.make_coded_slot` with ``rv`` set."""
+        retx = info is not None
+        ex, resident = self._exec(scenario, retx)
+        args = {"seed": np.int32(seed),
+                "snr_db": np.float32(scenario.snr_db)}
+        if retx:
+            args["rv"] = np.int32(rv)
+            args["info"] = np.asarray(info, np.int32)
+        return ex(args), resident
+
+
 class CellLoop:
-    """The per-cell closed-loop state machine (no execution, no jax).
+    """The per-cell closed-loop state machine (no pipeline execution).
 
     Owns everything about one logical cell *except* running pipelines:
     per-user queues and link-adaptation state, Poisson arrivals, HARQ
@@ -690,10 +796,12 @@ class CellLoop:
                  snr_db: Optional[float] = None,
                  snr_spread_db: float = 0.0,
                  interferer_db: tuple = (), uid_base: int = 0,
-                 job_ids=None):
+                 job_ids=None, slot_gen: Optional[SlotGenerator] = None):
         self.name = name
         self.rungs = list(rungs)
         self.rng = rng
+        # compiled slot generation, shared by every loop of a scheduler
+        self.slot_gen = slot_gen if slot_gen is not None else SlotGenerator()
         # co-channel interferer powers (dB rel. signal) appended to every
         # served rung's own interferer list — the mesh's coupling wiring
         # sets this from same-group neighbor tx powers.  Empty () leaves
@@ -745,9 +853,6 @@ class CellLoop:
         self.jobs_shed = 0
 
     # -- traffic ----------------------------------------------------------
-    def next_key(self) -> jax.Array:
-        return rng_key(self.rng)
-
     def _new_job(self) -> _Job:
         self._arrivals += 1
         return _Job(enq_tick=self.now, job_id=next(self._job_ids))
@@ -768,6 +873,13 @@ class CellLoop:
                 stats.n_arrivals += 1
 
     # -- slot construction ------------------------------------------------
+    def prebuild_slots(self) -> None:
+        """Acquire the slot generator's executables for every rung, so no
+        transmission this loop sends compiles."""
+        for scn in self.rungs:
+            self.slot_gen.prebuild(self._tx_scenario(scn, scn.snr_db),
+                                   self.max_retx)
+
     def make_slot(self, user: UserState, job: _Job, mcs: int) -> dict:
         """Build the (re)transmission slot for one job.
 
@@ -775,16 +887,19 @@ class CellLoop:
         batch's rung) and allocates the HARQ process; retransmissions
         re-encode the pinned process's blocks at its next RV over a
         fresh channel realization, with the combined-LLR buffer riding
-        as the prior.
+        as the prior.  Either is one call of a compiled
+        :class:`SlotGenerator` executable.
         """
         from repro.phy import coding
 
-        with span("serve.make_slot", retx=int(job.harq is not None)):
+        with span("serve.make_slot",
+                  retx=int(job.harq is not None)) as sp:
+            seed = rng_seed(self.rng)
             if job.harq is None:
                 scn = self.rungs[mcs]
                 n_cw = coding.codewords_per_slot(scn)
-                slot = coding.make_coded_slot(
-                    self.next_key(), self._tx_scenario(scn, user), 1, rv=0
+                slot, prebuilt = self.slot_gen(
+                    self._tx_scenario(scn, user.snr_db), seed
                 )
                 job.harq = HarqProcess(
                     mcs=mcs,
@@ -797,22 +912,23 @@ class CellLoop:
             else:
                 h = job.harq
                 scn = self.rungs[h.mcs]  # retx pins the MCS of the first tx
-                slot = coding.make_coded_slot(
-                    self.next_key(), self._tx_scenario(scn, user), 1,
+                slot, prebuilt = self.slot_gen(
+                    self._tx_scenario(scn, user.snr_db), seed,
                     rv=h.rv, info=h.info,
                 )
+            sp.set_metadata(compiled=int(prebuilt))
             slot["prior_llr"] = job.harq.prior
             return slot
 
-    def _tx_scenario(self, scn, user: UserState):
+    def _tx_scenario(self, scn, snr_db: float):
         """The per-transmission scenario: the rung at the user's SNR, plus
         any cell-level co-channel interference on top of the rung's own."""
         if self.interferer_db:
             return scn.replace(
-                snr_db=user.snr_db,
+                snr_db=snr_db,
                 interferer_db=tuple(scn.interferer_db) + self.interferer_db,
             )
-        return scn.replace(snr_db=user.snr_db)
+        return scn.replace(snr_db=snr_db)
 
     # -- feedback ---------------------------------------------------------
     def serve_feedback(self, user: UserState, job: _Job, mcs: int,
@@ -1059,10 +1175,11 @@ class SlotScheduler:
     seed: the single seed behind every random draw (arrivals, SNR
         spread, slot/channel/noise realizations) via :func:`cell_rng` —
         two schedulers with equal config + seed replay identically.
-    prebuild: AOT-compile every rung's executable at construction through
-        the :class:`~repro.serve.exec_registry.ExecRegistry` (all cache
-        hits on a warm persistent cache); ``False`` defers each rung to
-        its first served batch.
+    prebuild: AOT-compile every rung's executable, and the slot
+        generator's, at construction through the
+        :class:`~repro.serve.exec_registry.ExecRegistry` (all cache hits
+        on a warm persistent cache); ``False`` defers each to its first
+        use.
     registry: explicit :class:`ExecRegistry` (default: the process-wide
         registry, shared with every other engine in the process).
     """
@@ -1108,7 +1225,10 @@ class SlotScheduler:
             target_bler=target_bler, olla_step=olla_step,
             init_mcs=init_mcs, snr_db=snr_db,
             snr_spread_db=snr_spread_db, interferer_db=interferer_db,
+            slot_gen=SlotGenerator(registry=registry),
         )
+        if prebuild:
+            self.loop.prebuild_slots()
         self.ledger = SlotLedger()
 
     # delegation: the state machine is the source of truth
@@ -1189,7 +1309,7 @@ class SlotScheduler:
             wall_s=sum(r.wall_s for r in self.runners),
             n_batches=sum(r.n_batches for r in self.runners),
         )
-        stats = ExecStats()
+        stats = ExecStats().merge(self.loop.slot_gen.stats)
         for r in self.runners:
             stats.merge(r.exec_stats)
         first_s, steady_s = first_steady(self.tick_times)

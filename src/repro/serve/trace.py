@@ -12,7 +12,9 @@ code that drives the loop.  The spans and what they hold:
 * ``serve.tick`` (``slots``): one ``MeshSlotScheduler.tick``, with its
   ``serve.arrive``, ``serve.rebalance``, ``serve.plan`` (``batches``)
   and ``serve.end_tick`` phases;
-* ``serve.make_slot`` (``retx`` 0/1): one user's slot built on the host;
+* ``serve.make_slot`` (``retx`` 0/1, ``compiled``): one user's slot,
+  built by one call of a slot-generator executable (``compiled`` 1 when
+  it was prebuilt, 0 when this call built it);
 * ``serve.stage`` (``lanes``, ``bucket``): one mesh step's lanes stacked
   and put on the device;
 * ``serve.dispatch`` (mesh: ``lanes``, ``bucket``, ``mcs``): one served

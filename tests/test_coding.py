@@ -340,6 +340,25 @@ def test_coded_slot_grid_mapping_roundtrip():
                                   np.asarray(expect == 1))
 
 
+def test_data_re_index_first_built_inside_a_trace():
+    """The data-RE index is cached per grid; built first inside ``jit``
+    it must not keep that trace's tracer for the next trace or an eager
+    call on the same grid."""
+    scn = _small("siso-qpsk-r12-snr8")
+    # a grid no other test uses, so the first index build is in the trace
+    scn = scn.replace(grid=dataclasses.replace(scn.grid, n_subcarriers=60))
+    llr = jnp.ones((1, scn.grid.n_symbols, scn.grid.n_subcarriers, 1, 2))
+    jax.jit(lambda x: coding.coded_llrs(scn, x))(llr)
+    jax.jit(lambda x: coding.coded_llrs(scn, x * 2.0))(llr)
+    jax.jit(lambda k: coding.make_coded_slot(k, scn, 1)["bits"])(KEY)
+    slot = coding.make_coded_slot(KEY, scn, 1)
+    np.testing.assert_array_equal(
+        np.asarray(coding.coded_llrs(scn, slot["bits"])),
+        np.asarray(coding.rate_match(scn.code, coding.encode(
+            scn.code, coding.crc_attach(slot["info_bits"])))),
+    )
+
+
 def test_coded_scenarios_registered_and_build_everywhere():
     coded = [n for n in scenario_names() if get_scenario(n).coded]
     assert len(coded) >= 4
