@@ -10,8 +10,10 @@ receiver stages the same treatment the neural hot paths already get:
   emits unbiased max-log LLRs — without ever materializing ``h_eff`` /
   Gram / equalized-symbol grids in HBM.
 * ``ls_che`` — fused LS channel estimation: DMRS comb extract → per-pilot
-  divide → frequency interpolation, folded into one complex GEMM against a
-  precomputed interpolation operator (TE work instead of PE gather/lerp).
+  divide → frequency interpolation, folded into a banded complex GEMM per
+  subcarrier tile against a precomputed interpolation operator (TE work
+  instead of PE gather/lerp).  A tile reads only the pilots it needs, so
+  its VMEM (:func:`ls_che_vmem_bytes`) does not grow with the carrier.
 
 Pallas has no complex dtype, so everything runs in a split-complex planar
 FP32 layout: real/imag components (and the small antenna dims) are stacked
@@ -28,6 +30,7 @@ through the :mod:`repro.kernels.tune` cache before static defaults.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Sequence
 
@@ -46,6 +49,13 @@ def _use_pallas(use_pallas: Optional[bool]) -> bool:
     if use_pallas is None:
         return jax.default_backend() == "tpu"
     return use_pallas
+
+
+_LANE, _SUBLANE = 128, 8  # Mosaic's f32 tile
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _cmul(ar, ai, br, bi):
@@ -510,42 +520,131 @@ def mmse_detect_demap_int8(
 # fused LS channel estimation
 # ---------------------------------------------------------------------------
 
-def make_ls_interp_operator(n_sc: int, n_tx: int, pilot_stride: int,
-                            seq: np.ndarray) -> jax.Array:
-    """(n_tx, n_p, n_sc) complex operator folding the per-pilot divide and
-    the clamped linear frequency interpolation into one GEMM:
+LS_MIN_BLOCK_SC = 256  # least subcarriers of one LS-CHE tile
 
-        H_ls[..., t] = ybar[comb_t] @ op[t]
 
-    where ``ybar`` is the pilot-symbol average of the received grid and
-    ``comb_t`` the stride-``pilot_stride * n_tx`` DMRS comb of tx ``t``.
-    Pilot sequences are unit power, so dividing by ``seq`` is multiplying
-    by its conjugate — which folds into the operator.
-    """
+def _interp_weights(n_sc: int, n_tx: int, pilot_stride: int,
+                    seq: np.ndarray) -> tuple:
+    """Clamped linear interpolation from each tx's DMRS comb, with the
+    per-pilot divide folded in.  Returns pilot indices (n_tx, n_sc, 2)
+    into the comb and complex weights (n_tx, n_sc, 2): subcarrier ``s``
+    of tx ``t`` is ``sum_k w[t, s, k] * ybar[comb_t][idx[t, s, k]]``."""
     spacing = pilot_stride * n_tx
     assert n_sc % spacing == 0, (
         f"n_sc={n_sc} not a multiple of the comb spacing {spacing}"
     )
     n_p = n_sc // spacing
     seq = np.asarray(seq)
-    pos = np.arange(n_sc, dtype=np.float64)
-    op = np.zeros((n_tx, n_p, n_sc), np.complex64)
+    s = np.arange(n_sc)
+    idx = np.zeros((n_tx, n_sc, 2), np.int64)
+    w = np.zeros((n_tx, n_sc, 2), np.complex64)
     for t in range(n_tx):
-        p_idx = np.arange(t * pilot_stride, n_sc, spacing)
-        xp = pos[p_idx]
-        for s in range(n_sc):
-            x = pos[s]
-            if x <= xp[0]:
-                w = {0: 1.0}
-            elif x >= xp[-1]:
-                w = {n_p - 1: 1.0}
-            else:
-                i = int(np.searchsorted(xp, x, side="right") - 1)
-                f = (x - xp[i]) / (xp[i + 1] - xp[i])
-                w = {i: 1.0 - f, i + 1: f}
-            for i, wt in w.items():
-                op[t, i, s] += wt * np.conj(seq[p_idx[i]])
-    return jnp.asarray(op)
+        p_idx = t * pilot_stride + spacing * np.arange(n_p)
+        i = np.clip((s - t * pilot_stride) // spacing, 0, max(n_p - 2, 0))
+        j = np.minimum(i + 1, n_p - 1)
+        # f = 0 left of the first pilot, 1 right of the last: clamped ends
+        f = np.clip((s - p_idx[i]) / spacing, 0.0, 1.0) if n_p > 1 else 0.0
+        idx[t, :, 0], idx[t, :, 1] = i, j
+        w[t, :, 0] = (1.0 - f) * np.conj(seq[p_idx[i]])
+        w[t, :, 1] = f * np.conj(seq[p_idx[j]])
+    return idx, w
+
+
+@functools.partial(jax.tree_util.register_dataclass, data_fields=["band"],
+                   meta_fields=["n_sc", "pilot_stride", "block_sc"])
+@dataclasses.dataclass(frozen=True)
+class LsInterpOperator:
+    """The LS interpolation operator, cut into subcarrier tiles.
+
+    Tile ``j`` holds subcarriers ``[j * block_sc, (j + 1) * block_sc)``
+    and needs only the ``width`` comb pilots ``j * P - 1 ...`` of each tx
+    (``P = block_sc / spacing`` pilots lie inside the tile, one more on
+    each side).  ``band[t, w, s]`` is the weight of tile ``s // block_sc``'s
+    ``w``-th window pilot for subcarrier ``s``; the subcarriers past
+    ``n_sc`` in the last tile have none.  Built by
+    :func:`make_ls_interp_operator`.
+    """
+    band: jax.Array  # (n_tx, width, n_tiles * block_sc) complex64
+    n_sc: int
+    pilot_stride: int
+    block_sc: int
+
+    @property
+    def n_tx(self) -> int:
+        return self.band.shape[0]
+
+    @property
+    def width(self) -> int:
+        return self.band.shape[1]
+
+    @property
+    def spacing(self) -> int:
+        return self.pilot_stride * self.n_tx
+
+    @property
+    def n_p(self) -> int:
+        return self.n_sc // self.spacing
+
+    @property
+    def n_tiles(self) -> int:
+        return self.band.shape[2] // self.block_sc
+
+    def dense(self, seq: np.ndarray) -> jax.Array:
+        """(n_tx, n_p, n_sc) dense operator of :func:`ls_che_jnp`, built
+        from the interpolation weights and not from ``band``."""
+        idx, w = _interp_weights(self.n_sc, self.n_tx, self.pilot_stride,
+                                 seq)
+        op = np.zeros((self.n_tx, self.n_p, self.n_sc), np.complex64)
+        t, s = np.meshgrid(np.arange(self.n_tx), np.arange(self.n_sc),
+                           indexing="ij")
+        for k in range(2):
+            np.add.at(op, (t, idx[..., k], s), w[..., k])
+        return jnp.asarray(op)
+
+
+def ls_block_sc(n_tx: int, pilot_stride: int) -> int:
+    """Subcarrier tile of the LS-CHE kernel: the least multiple of the
+    128-lane quantum and of the comb spacing that is at least
+    :data:`LS_MIN_BLOCK_SC`."""
+    q = np.lcm(_LANE, pilot_stride * n_tx)
+    return int(_round_up(LS_MIN_BLOCK_SC, q))
+
+
+def make_ls_interp_operator(n_sc: int, n_tx: int, pilot_stride: int,
+                            seq: np.ndarray) -> LsInterpOperator:
+    """Fold the per-pilot divide and the clamped linear frequency
+    interpolation into one banded operator, tiled over subcarriers:
+
+        H_ls[s, t] = sum_w window_j(ybar[comb_t])[w] * band[t, w, s]
+
+    where ``ybar`` is the pilot-symbol average of the received grid,
+    ``comb_t`` the stride-``pilot_stride * n_tx`` DMRS comb of tx ``t``
+    and ``window_j`` the ``width`` pilots tile ``j = s // block_sc``
+    reads.  Pilot sequences are unit power, so dividing by ``seq`` is
+    multiplying by its conjugate, which folds into the operator.
+
+    Accepts any ``n_sc`` that the comb spacing divides (a multiple of 128
+    or not: the last tile is padded).  Tiles are :func:`ls_block_sc`
+    wide, a multiple of 128 and of the spacing; the operator holds
+    ``n_tx * width * block_sc`` weights a tile, with
+    ``width = block_sc / spacing + 2`` rounded up to 8, whatever ``n_sc``,
+    and a kernel grid step needs :func:`ls_che_vmem_bytes` of VMEM (0.95
+    MB at batch 8 x 4 rx, one tx, 256-subcarrier tiles).
+    """
+    spacing = pilot_stride * n_tx
+    idx, w = _interp_weights(n_sc, n_tx, pilot_stride, seq)
+    bs = ls_block_sc(n_tx, pilot_stride)
+    width = _round_up(bs // spacing + 2, _SUBLANE)
+    n_pad = _round_up(n_sc, bs)
+    s = np.arange(n_sc)
+    # window position: tile j's window starts at comb pilot j * P - 1
+    pos = idx - ((s // bs) * (bs // spacing) - 1)[None, :, None]
+    assert pos.min() >= 0 and pos.max() < width
+    band = np.zeros((n_tx, width, n_pad), np.complex64)
+    t, ss = np.meshgrid(np.arange(n_tx), s, indexing="ij")
+    for k in range(2):
+        np.add.at(band, (t, pos[..., k], ss), w[..., k])
+    return LsInterpOperator(jnp.asarray(band), n_sc, pilot_stride, bs)
 
 
 def _comb_extract(y: jax.Array, pilot_symbols: tuple, pilot_stride: int,
@@ -558,12 +657,25 @@ def _comb_extract(y: jax.Array, pilot_symbols: tuple, pilot_stride: int,
     )
 
 
+def _pilot_windows(x: jax.Array, op: LsInterpOperator) -> jax.Array:
+    """(..., n_p) comb pilots -> (..., n_tiles, width): tile ``j`` gets
+    pilots ``j * P - 1 ... j * P + width - 2``, zero past either end."""
+    per = op.block_sc // op.spacing
+    total = (op.n_tiles - 1) * per + op.width
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                 + [(1, total - 1 - x.shape[-1])])
+    return jnp.stack([xp[..., j * per : j * per + op.width]
+                      for j in range(op.n_tiles)], axis=-2)
+
+
 def ls_che_jnp(
     y: jax.Array,  # (B, n_sym, n_sc, n_rx) complex
     pilot_symbols: tuple,
     pilot_stride: int,
-    op: jax.Array,  # (n_tx, n_p, n_sc) from make_ls_interp_operator
+    op: jax.Array,  # (n_tx, n_p, n_sc), LsInterpOperator.dense
 ) -> jax.Array:
+    """LS CHE as one dense GEMM against the whole operator: the plain
+    form the tiled routes are checked against."""
     n_tx = op.shape[0]
     comb = jnp.mean(
         _comb_extract(y, pilot_symbols, pilot_stride, n_tx), axis=1
@@ -571,40 +683,84 @@ def ls_che_jnp(
     return jnp.einsum("btpr,tps->bsrt", comb, op)
 
 
-def _ls_che_kernel(yc_ref, opr_ref, o_ref, *, n_psym: int, n_tx: int):
-    """Grid: (row_tiles,).  Pilot-symbol average + split-complex interp GEMM
-    per tx; the per-pilot LS estimates never leave VMEM."""
+def ls_che_tiled_jnp(
+    y: jax.Array,  # (B, n_sym, n_sc, n_rx) complex
+    pilot_symbols: tuple,
+    op: LsInterpOperator,
+) -> jax.Array:
+    """The kernel's tiled math on whole grids (the off-TPU route)."""
+    comb = jnp.mean(
+        _comb_extract(y, pilot_symbols, op.pilot_stride, op.n_tx), axis=1
+    )  # (B, n_tx, n_p, n_rx)
+    win = _pilot_windows(jnp.moveaxis(comb, 2, -1), op)  # (B,t,r,j,w)
+    band = op.band.reshape(op.n_tx, op.width, op.n_tiles, op.block_sc)
+    h = jnp.einsum("btrjw,twjs->bjsrt", win, band)
+    return h.reshape(h.shape[0], -1, *h.shape[3:])[:, : op.n_sc]
+
+
+def _ls_che_kernel(yc_ref, op_ref, o_ref, *, n_psym: int, n_tx: int):
+    """Grid: (row_tiles, sc_tiles).  Pilot-symbol average of the tile's
+    pilot window + split-complex banded interp GEMM per tx; the per-pilot
+    LS estimates never leave VMEM."""
     inv = 1.0 / n_psym
     for t in range(n_tx):
-        er = sum(yc_ref[p * n_tx + t] for p in range(n_psym)) * inv
-        ei = sum(yc_ref[(n_psym + p) * n_tx + t]
-                 for p in range(n_psym)) * inv  # (bm, n_p)
-        mr, mi = opr_ref[t], opr_ref[n_tx + t]  # (n_p, n_sc)
+        er = sum(yc_ref[0, p * n_tx + t] for p in range(n_psym)) * inv
+        ei = sum(yc_ref[0, (n_psym + p) * n_tx + t]
+                 for p in range(n_psym)) * inv  # (bm, width)
+        mr, mi = op_ref[t], op_ref[n_tx + t]  # (width, bs)
         dot = lambda a, b: jnp.dot(a, b, preferred_element_type=jnp.float32)
         o_ref[t] = dot(er, mr) - dot(ei, mi)
         o_ref[n_tx + t] = dot(er, mi) + dot(ei, mr)
+
+
+def ls_che_vmem_bytes(n_tx: int, n_psym: int, block_rows: int,
+                      block_sc: int, width: int) -> int:
+    """VMEM of one ``rx_ls_che`` grid step, double-buffered, f32 blocks
+    padded to Mosaic's (8, 128) tile: the pilot window
+    ``(2 * n_psym * n_tx, block_rows, width)``, the operator tile
+    ``(2 * n_tx, width, block_sc)`` and the output tile
+    ``(2 * n_tx, block_rows, block_sc)``.  None of them holds ``n_sc``."""
+    rows = _round_up(block_rows, _SUBLANE)
+    pilots = 2 * n_psym * n_tx * rows * _round_up(width, _LANE)
+    band = 2 * n_tx * _round_up(width, _SUBLANE) * block_sc
+    out = 2 * n_tx * rows * block_sc
+    return 2 * 4 * (pilots + band + out)
+
+
+def _ls_block_rows(rows: int, n_sc: int, n_rx: int, n_tx: int,
+                   n_p: int) -> int:
+    """Row tile: a tuned choice, else the largest of 64/32/16/8 that
+    divides ``rows``, else all rows (Mosaic's 8-row quantum)."""
+    cached = tune.cached_choice("rx_ls_che", (n_sc, n_rx, n_tx, n_p))
+    if cached and rows % cached[0] == 0:
+        return cached[0]
+    return next((c for c in (64, 32, 16, 8) if rows % c == 0), rows)
 
 
 def ls_che_pallas(
     y: jax.Array,
     pilot_symbols: tuple,
     pilot_stride: int,
-    op: jax.Array,
+    op: LsInterpOperator,
     *,
     block_rows: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
+    """Fused LS CHE as one Pallas pass over (row tile, subcarrier tile).
+
+    Each grid step reads one row tile's pilot window and one operator
+    tile and writes one ``h_ls`` tile, so its VMEM
+    (:func:`ls_che_vmem_bytes`) is set by the tile shapes and not by
+    ``n_sc``; the last subcarrier tile is padded and the padding sliced
+    off.  Returns (B, n_sc, n_rx, n_tx)."""
+    assert pilot_stride == op.pilot_stride
     interpret = resolve_interpret(interpret)
     b, n_sym, n_sc, n_rx = y.shape
-    n_tx, n_p, _ = op.shape
-    n_psym = len(pilot_symbols)
+    n_tx, n_psym = op.n_tx, len(pilot_symbols)
+    width, bs = op.width, op.block_sc
     rows = b * n_rx
-    if block_rows is None:
-        cached = tune.cached_choice("rx_ls_che", (n_sc, n_rx, n_tx, n_p))
-        block_rows = (cached[0] if cached and rows % cached[0] == 0
-                      else next((c for c in (64, 32, 16, 8, 4, 2, 1)
-                                 if rows % c == 0), rows))
-    bm = min(block_rows, rows)
+    bm = min(block_rows or _ls_block_rows(rows, n_sc, n_rx, n_tx, op.n_p),
+             rows)
     assert rows % bm == 0
 
     f32 = jnp.float32
@@ -612,25 +768,30 @@ def ls_che_pallas(
     # (2, n_psym, n_tx, rows, n_p): component-major planar layout
     yc = jnp.stack([jnp.real(comb), jnp.imag(comb)], 0)
     yc = jnp.transpose(yc, (0, 2, 3, 1, 5, 4)).reshape(
-        2 * n_psym * n_tx, rows, n_p
+        2 * n_psym * n_tx, rows, op.n_p
     )
-    opp = jnp.concatenate([jnp.real(op), jnp.imag(op)], 0)  # (2*n_tx, p, sc)
+    win = jnp.moveaxis(_pilot_windows(yc, op), 2, 0)  # (tiles, C, rows, w)
+    opp = jnp.concatenate([jnp.real(op.band), jnp.imag(op.band)], 0)
 
     kernel = functools.partial(_ls_che_kernel, n_psym=n_psym, n_tx=n_tx)
     out = pl.pallas_call(
         kernel,
-        grid=(rows // bm,),
+        grid=(rows // bm, op.n_tiles),
         in_specs=[
-            pl.BlockSpec((2 * n_psym * n_tx, bm, n_p), lambda i: (0, i, 0)),
-            pl.BlockSpec((2 * n_tx, n_p, n_sc), lambda i: (0, 0, 0)),
+            pl.BlockSpec((1, 2 * n_psym * n_tx, bm, width),
+                         lambda i, j: (j, 0, i, 0)),
+            pl.BlockSpec((2 * n_tx, width, bs), lambda i, j: (0, 0, j)),
         ],
-        out_specs=pl.BlockSpec((2 * n_tx, bm, n_sc), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((2 * n_tx, rows, n_sc), f32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        out_specs=pl.BlockSpec((2 * n_tx, bm, bs), lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((2 * n_tx, rows, op.n_tiles * bs),
+                                       f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="rx_ls_che",
-    )(yc.astype(f32), opp.astype(f32))
+    )(win.astype(f32), opp.astype(f32))
 
+    out = out[..., :n_sc]
     h = (out[:n_tx] + 1j * out[n_tx:]).reshape(n_tx, b, n_rx, n_sc)
     return jnp.transpose(h, (1, 3, 2, 0))  # (B, n_sc, n_rx, n_tx)
 
@@ -639,16 +800,18 @@ def ls_che(
     y: jax.Array,
     pilot_symbols: tuple,
     pilot_stride: int,
-    op: jax.Array,
+    op: LsInterpOperator,
     *,
     block_rows: Optional[int] = None,
     use_pallas: Optional[bool] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Fused LS CHE (comb extract → divide → interp); backend-dispatched."""
+    """Fused LS CHE (comb extract → divide → interp) against the tiled
+    operator; backend-dispatched (Pallas on TPU, the same tiled math as
+    jnp elsewhere)."""
     if _use_pallas(use_pallas):
         return ls_che_pallas(
             y, pilot_symbols, pilot_stride, op,
             block_rows=block_rows, interpret=interpret,
         )
-    return ls_che_jnp(y, pilot_symbols, pilot_stride, op)
+    return ls_che_tiled_jnp(y, pilot_symbols, op)

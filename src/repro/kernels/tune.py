@@ -407,9 +407,9 @@ def autotune_rx_ls_che(batch: int, n_sym: int, n_sc: int, n_rx: int,
     seq = np.exp(1j * (np.pi / 4 + np.pi / 2 * (np.arange(n_sc) % 4)))
     op = _rx.make_ls_interp_operator(n_sc, n_tx, pilot_stride, seq)
     rows = batch * n_rx
-    cands = [(bm,) for bm in _divisor_cands(rows, (64, 32, 16, 8, 4, 2))]
+    cands = [(bm,) for bm in _divisor_cands(rows, (64, 32, 16, 8))]
     return autotune(
-        "rx_ls_che", (n_sc, n_rx, n_tx, op.shape[1]), cands,
+        "rx_ls_che", (n_sc, n_rx, n_tx, op.n_p), cands,
         lambda c: _rx.ls_che_pallas(
             y, pilot_symbols, pilot_stride, op, block_rows=c[0]
         ),

@@ -212,8 +212,9 @@ def ls_che_stage(cfg: ofdm.GridConfig, fused: bool = False) -> RxStage:
 
     ``fused=True`` routes through :mod:`repro.kernels.rx_fused`: comb
     extract → per-pilot divide → frequency interpolation folded into one
-    complex GEMM against a precomputed operator — TE work with the
-    per-pilot estimates resident in L1, instead of the PE gather/lerp.
+    banded complex GEMM per subcarrier tile against a precomputed operator
+    — TE work with the per-pilot estimates resident in L1, instead of the
+    PE gather/lerp.
     """
     seq = ofdm.pilot_sequence(cfg)
     n_sc, n_psym = cfg.n_subcarriers, len(cfg.pilot_symbols)
@@ -221,7 +222,7 @@ def ls_che_stage(cfg: ofdm.GridConfig, fused: bool = False) -> RxStage:
         op = rx_fused.make_ls_interp_operator(
             n_sc, cfg.n_tx, cfg.pilot_stride, np.asarray(seq)
         )
-        n_p = op.shape[1]
+        n_p, width, n_pad = op.n_p, op.width, op.band.shape[-1]
 
         def apply(state):
             state["h_ls"] = rx_fused.ls_che(
@@ -230,8 +231,9 @@ def ls_che_stage(cfg: ofdm.GridConfig, fused: bool = False) -> RxStage:
             return state
 
         def cycles():
-            # split-complex interp GEMM on the TEs; pilot averaging on PEs
-            macs = 4.0 * cfg.n_rx * cfg.n_tx * n_p * n_sc
+            # split-complex banded interp GEMM on the TEs (each tile
+            # reads its pilot window); pilot averaging on PEs
+            macs = 4.0 * cfg.n_rx * cfg.n_tx * width * n_pad
             flops = 2.0 * n_psym * cfg.n_tx * n_p * cfg.n_rx
             return pool.BlockCycles(
                 te_cycles=pool.te_cycles(macs, utilization=0.67),

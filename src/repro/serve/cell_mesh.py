@@ -74,7 +74,7 @@ from repro.serve.runtime import (
     build_serve_report, cell_rng, first_steady, make_traffic,
     occupancy_energy, resolve_ladder, stack_slots,
 )
-from repro.serve.trace import span, step_window, wait
+from repro.serve.trace import shape_counts, span, step_window, wait
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1100,7 +1100,8 @@ class MeshSlotScheduler:
         """
         bucket = self._bucket(len(lanes))
         step = self._step_for(gi, mcs, bucket, staged)
-        with step_window(self, lanes=len(lanes), bucket=bucket, mcs=mcs):
+        with step_window(self, lanes=len(lanes), bucket=bucket, mcs=mcs,
+                         **shape_counts(self.groups[gi].rungs[mcs])):
             state = step(staged)  # async dispatch
             nxt = prefetch() if prefetch is not None else None
             state = wait(state)

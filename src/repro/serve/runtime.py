@@ -62,7 +62,7 @@ from repro.phy import link as _link
 from repro.serve.exec_registry import (
     ExecKey, ExecStats, get_registry, slot_schema, template_batch,
 )
-from repro.serve.trace import span, step_window, wait
+from repro.serve.trace import shape_counts, span, step_window, wait
 
 # slot keys with a leading per-user batch axis; everything else is
 # scenario-static side info shared by every user.  "info_bits" only
@@ -387,6 +387,7 @@ class BatchRunner:
         self.n_batches = 0
         self.batch_times: list[float] = []
         self._execs: dict = {}  # slot schema -> AOT-compiled step
+        self.span_counts = shape_counts(pipeline.scenario)
 
     def prepare(self, batch: dict):
         """Acquire the AOT step for ``batch``'s slot structure (no
@@ -416,7 +417,7 @@ class BatchRunner:
         """Run one stacked batch inside the timed window.  Overridable:
         :class:`repro.serve.supervisor.SupervisedBatchRunner` interposes
         retry and non-finite-guard handling here."""
-        with step_window(self) as window:
+        with step_window(self, **self.span_counts) as window:
             state = wait(self._step(batch))
         self.batch_times.append(window["dt"])
         return state
@@ -427,7 +428,7 @@ class BatchRunner:
         Marks each request done with its per-slot metrics; padded tail
         results are discarded.
         """
-        with span("serve.batch", slots=len(reqs)):
+        with span("serve.batch", slots=len(reqs), **self.span_counts):
             with span("serve.stack"):
                 batch = stack_slots(
                     [r.slot for r in reqs], self.batch_size - len(reqs)

@@ -78,7 +78,7 @@ from repro.serve.faults import FaultInjector, FaultPlan, InjectedFault
 from repro.serve.runtime import (
     BatchRunner, CellLoop, HarqProcess, TickStats, UserState, _Job,
 )
-from repro.serve.trace import step_window, wait
+from repro.serve.trace import shape_counts, step_window, wait
 
 __all__ = [
     "SupervisedBatchRunner", "Supervisor",
@@ -249,7 +249,7 @@ class SupervisedBatchRunner(BatchRunner):
         state = None
         for attempt in range(self.max_retries + 1):
             try:
-                with step_window(self) as window:
+                with step_window(self, **self.span_counts) as window:
                     state = wait(self._step(batch))
                 self.batch_times.append(window["dt"])
                 break
@@ -262,7 +262,7 @@ class SupervisedBatchRunner(BatchRunner):
         if not self._guard_ok(state):
             self.degraded_batches += 1
             ref = self._ref_exec(batch)
-            with step_window(self):
+            with step_window(self, **self.span_counts):
                 state = wait(ref(batch))
         return state
 
@@ -552,7 +552,8 @@ class Supervisor(MeshSlotScheduler):
 
         nxt, prefetched = None, False
         state = None
-        counts = dict(lanes=len(lanes), bucket=bucket, mcs=mcs)
+        counts = dict(lanes=len(lanes), bucket=bucket, mcs=mcs,
+                      **shape_counts(self.groups[gi].rungs[mcs]))
         for attempt in range(self.max_step_retries + 1):
             ev = self.injector.step_error(self.now, seq)
             try:

@@ -17,12 +17,14 @@ code that drives the loop.  The spans and what they hold:
   it was prebuilt, 0 when this call built it);
 * ``serve.stage`` (``lanes``, ``bucket``): one mesh step's lanes stacked
   and put on the device;
-* ``serve.dispatch`` (mesh: ``lanes``, ``bucket``, ``mcs``): one served
-  step's timed window, step call to the end of its ``serve.wait``;
+* ``serve.dispatch`` (mesh: ``lanes``, ``bucket``, ``mcs``; with the
+  step's :func:`shape_counts`): one served step's timed window, step
+  call to the end of its ``serve.wait``;
 * ``serve.wait``: the host blocked on a served step's outputs;
 * ``serve.feedback`` (``lanes``): CRC results fanned back to the cells;
-* ``serve.batch`` (``slots``, the real ones), with ``serve.stack`` and
-  ``serve.slot_metrics``: one ``BatchRunner.run_batch``;
+* ``serve.batch`` (``slots``, the real ones, and :func:`shape_counts`),
+  with ``serve.stack`` and ``serve.slot_metrics``: one
+  ``BatchRunner.run_batch``;
 * ``serve.report``: a report built after ``PhyServeEngine.run``;
 * ``serve.acquire`` (``compiled``, ``cache_hit``): an executable built,
   or loaded from the persistent cache, by the registry.
@@ -38,6 +40,15 @@ import jax
 def span(name: str, **counts) -> jax.profiler.TraceAnnotation:
     """A ``serve.*`` span carrying ``counts`` as its stats."""
     return jax.profiler.TraceAnnotation(name, **counts)
+
+
+def shape_counts(scenario) -> dict:
+    """The width of a served step, as span stats: ``n_sc`` (subcarriers)
+    and ``codewords`` (code blocks of one slot; 0 when uncoded)."""
+    from repro.phy import coding
+
+    cw = coding.codewords_per_slot(scenario) if scenario.code else 0
+    return {"n_sc": scenario.grid.n_subcarriers, "codewords": cw}
 
 
 def wait(x):

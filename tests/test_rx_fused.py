@@ -124,12 +124,12 @@ def test_ls_che_pallas_matches_jnp_path():
     scn = _small("mimo2x2-qam16-snr16")
     cfg = scn.grid
     slot = scn.make_batch(KEY, 2)
+    seq = np.asarray(ofdm.pilot_sequence(cfg))
     op = rx_fused.make_ls_interp_operator(
-        cfg.n_subcarriers, cfg.n_tx, cfg.pilot_stride,
-        np.asarray(ofdm.pilot_sequence(cfg)),
+        cfg.n_subcarriers, cfg.n_tx, cfg.pilot_stride, seq,
     )
     a = rx_fused.ls_che_jnp(
-        slot["y"], cfg.pilot_symbols, cfg.pilot_stride, op
+        slot["y"], cfg.pilot_symbols, cfg.pilot_stride, op.dense(seq)
     )
     b = rx_fused.ls_che_pallas(
         slot["y"], cfg.pilot_symbols, cfg.pilot_stride, op,

@@ -11,6 +11,7 @@ The topology and everything built from it live in module-scoped fixtures,
 never at import time: only one process may load the TPU library, so a
 describe at collection would break the other test workers.
 """
+import dataclasses
 import os
 
 import jax
@@ -125,7 +126,8 @@ def test_ls_che_compiles(one_chip):
         np.exp(1j * np.pi / 4 * np.arange(g.n_subcarriers)),
     )
     y, _, _ = _rx_specs(scn, one_chip)
-    opspec = _spec(op.shape, jnp.complex64, one_chip)
+    opspec = dataclasses.replace(
+        op, band=_spec(op.band.shape, jnp.complex64, one_chip))
     names = _mosaic_kernels(
         lambda y, op: rx_fused.ls_che_pallas(
             y, g.pilot_symbols, g.pilot_stride, op, interpret=False
@@ -133,6 +135,46 @@ def test_ls_che_compiles(one_chip):
         y, opspec,
     )
     assert any("rx_ls_che" in n for n in names), names
+
+
+def _nr100(one_chip):
+    """A 100 MHz carrier (3276 subcarriers, not a multiple of 128) into 4
+    receive antennas, batch 8: the grid of ``nr100-simo1x4-fused``."""
+    scn = get_scenario("siso-qam16-r12-snr15")
+    g = dataclasses.replace(scn.grid, n_subcarriers=3276, fft_size=4096,
+                            n_rx=4)
+    scn = scn.replace(grid=g)
+    return scn, _rx_specs(scn, one_chip)
+
+
+def test_ls_che_compiles_at_100mhz(one_chip):
+    """The tiled LS-CHE kernel at 3276 x 4 rx.  The parent's kernel took
+    the whole operator (43 MB) as one block: it compiled alone, and ran
+    out of scoped VMEM inside the whole served step."""
+    scn, (y, _, _) = _nr100(one_chip)
+    g = scn.grid
+    op = rx_fused.make_ls_interp_operator(
+        g.n_subcarriers, g.n_tx, g.pilot_stride,
+        np.exp(1j * np.pi / 4 * np.arange(g.n_subcarriers)),
+    )
+    names = _mosaic_kernels(
+        lambda y: rx_fused.ls_che_pallas(
+            y, g.pilot_symbols, g.pilot_stride, op, interpret=False
+        ),
+        y,
+    )
+    assert any("rx_ls_che" in n for n in names), names
+
+
+def test_mmse_detect_demap_compiles_at_100mhz(one_chip):
+    scn, specs = _nr100(one_chip)
+    names = _mosaic_kernels(
+        lambda y, h, nv: rx_fused.mmse_detect_demap_pallas(
+            y, h, nv, scn.modem, interpret=False
+        ),
+        *specs,
+    )
+    assert any("rx_detect_demap" in n for n in names), names
 
 
 @pytest.mark.parametrize("scenario,options,kernels", [

@@ -157,3 +157,26 @@ def test_engine_run_spans(tmp_path, supervised):
     assert got["batch_host_us_per_slot"] > 0
     assert got["stack_us_per_slot"] > 0
     assert got["tti_host_ms"] is None
+
+
+def test_spans_carry_the_step_width(mesh_tick, tmp_path):
+    """``serve.batch`` and ``serve.dispatch`` name the width they served
+    (subcarriers and code blocks a slot), so a trace splits by it."""
+    from repro.phy import coding
+
+    scn = _small("siso-qam16-r12-snr15", "trc-qam16-r12")
+    width = {"n_sc": 64, "codewords": coding.codewords_per_slot(scn)}
+    eng = PhyServeEngine.from_scenario(scn, batch_size=2)
+    eng.submit_traffic(jax.random.PRNGKey(0), 3)
+    eng.run()
+    eng.submit_traffic(jax.random.PRNGKey(1), 3)
+    _, sp, _ = profiled(eng.run, str(tmp_path))
+    for name in ("serve.batch", "serve.dispatch"):
+        got = [{k: s.stats[k] for k in width} for s in sp.named(name)]
+        assert got == [width] * 2, name
+    _, _, tick, _, _ = mesh_tick
+    steps = tick.named("serve.dispatch")
+    ladder = [get_scenario(n) for n in get_ladder("trc-siso").rungs]
+    assert steps and all(
+        s.stats["n_sc"] == 64 and s.stats["codewords"]
+        == coding.codewords_per_slot(ladder[s.stats["mcs"]]) for s in steps)
