@@ -85,19 +85,51 @@ def ls_channel_estimate(
     return re + 1j * im
 
 
+def wiener_filter(
+    n_sc: int,
+    corr_len: float,
+    noise_var: jax.Array,
+    pos: jax.Array | None = None,
+) -> jax.Array:
+    """The Wiener smoother W = (R + sigma^2 I)^-1 R of an exponential
+    frequency correlation R = exp(-|i - j| / corr_len), in the orientation
+    the apply consumes: H_mmse[s] = sum_k W[s, k] H_ls[k].  R commutes with
+    (R + sigma^2 I)^-1, so this is R (R + sigma^2 I)^-1 too.
+
+    ``pos`` is the subcarrier index as float32.  Left out, it is an iota,
+    and R a function of ``n_sc`` alone that XLA is free to fold into an
+    n_sc x n_sc constant of the calling program; given as a runtime
+    argument, R can only be computed on the device (the serving filter
+    cache builds W this way, :mod:`repro.serve.exec_registry`).
+    """
+    if pos is None:
+        pos = jnp.arange(n_sc, dtype=jnp.float32)
+    d = jnp.abs(pos[:, None] - pos[None, :])
+    r = jnp.exp(-d / corr_len).astype(jnp.complex64)
+    eye = (d == 0).astype(jnp.complex64)
+    return jnp.linalg.solve(r + noise_var * eye, r)  # (n_sc, n_sc)
+
+
 def mmse_channel_estimate(
     h_ls: jax.Array,  # (B, n_sc) LS estimate
     noise_var: jax.Array,
     corr_len: float = 16.0,
 ) -> jax.Array:
     """Wiener smoothing of the LS estimate with an exponential frequency
-    correlation model: H_mmse = R (R + sigma^2 I)^-1 H_ls."""
-    n_sc = h_ls.shape[-1]
-    d = jnp.abs(jnp.arange(n_sc)[:, None] - jnp.arange(n_sc)[None, :])
-    r = jnp.exp(-d / corr_len).astype(jnp.complex64)
-    a = r + noise_var * jnp.eye(n_sc, dtype=jnp.complex64)
-    w = jnp.linalg.solve(a, r).T  # (n_sc, n_sc)
-    return jnp.einsum("sk,bk->bs", w.T, h_ls)
+    correlation model: H_mmse = R (R + sigma^2 I)^-1 H_ls.
+
+    The filter is built here, in the caller's graph
+    (:func:`wiener_filter`); the served step instead reads one the
+    serving filter cache built once per noise variance
+    (:func:`repro.phy.link.mmse_che_stage`)."""
+    w = wiener_filter(h_ls.shape[-1], corr_len, noise_var)
+    return wiener_apply(w, h_ls)
+
+
+def wiener_apply(w: jax.Array, h_ls: jax.Array) -> jax.Array:
+    """Apply a :func:`wiener_filter` ``w`` (n_sc, n_sc) to ``h_ls``
+    (B, n_sc)."""
+    return jnp.einsum("sk,bk->bs", w, h_ls)
 
 
 def _regularized_gram_rhs(
@@ -227,12 +259,11 @@ def ls_channel_estimate_link(
 
 def mmse_smooth_link(
     h_ls: jax.Array,  # (B, n_sc, n_rx, n_tx)
-    noise_var: jax.Array,
-    corr_len: float = 16.0,
+    w: jax.Array,  # (n_sc, n_sc) from wiener_filter
 ) -> jax.Array:
     """Wiener smoothing of a per-(rx, tx) LS estimate (folds antenna pairs
-    into the batch of :func:`mmse_channel_estimate`)."""
+    into the batch of :func:`wiener_apply`)."""
     b, n_sc, n_rx, n_tx = h_ls.shape
     flat = jnp.moveaxis(h_ls, 1, -1).reshape(b * n_rx * n_tx, n_sc)
-    sm = mmse_channel_estimate(flat, noise_var, corr_len=corr_len)
+    sm = wiener_apply(w, flat)
     return jnp.moveaxis(sm.reshape(b, n_rx, n_tx, n_sc), -1, 1)

@@ -53,6 +53,9 @@ class RxStage:
     compute: str  # dominant engine: "TE" | "PE" | "DMA"
     apply: Callable[[dict], dict]
     cycles: Optional[Callable[[], pool.BlockCycles]] = None
+    # the Wiener stage's correlation length: its step reads the filter
+    # from the slot's ``wiener_w`` when the server puts one there
+    wiener_corr_len: Optional[float] = None
 
 
 def _sum_cycles(cs) -> pool.BlockCycles:
@@ -94,6 +97,13 @@ class ReceiverPipeline:
     def run(self, slot: dict) -> dict:
         """Jitted end-to-end receive over a batch of slots."""
         return self._jitted(slot)
+
+    @property
+    def wiener_corr_len(self) -> Optional[float]:
+        """Correlation length of the stage that reads a Wiener filter
+        from ``wiener_w``, or None when no stage does."""
+        return next((st.wiener_corr_len for st in self.stages
+                     if st.wiener_corr_len is not None), None)
 
     # -- TensorPool budget ------------------------------------------------
     def stage_cycles(self) -> dict[str, pool.BlockCycles]:
@@ -273,14 +283,24 @@ def ls_che_stage(cfg: ofdm.GridConfig, fused: bool = False) -> RxStage:
 
 
 def mmse_che_stage(cfg: ofdm.GridConfig, corr_len: float = 16.0) -> RxStage:
-    """Wiener smoothing; the (n_sc x n_sc) filter is per-scenario and
-    amortized, the per-slot work is the matrix-vector apply per antenna
-    pair."""
+    """Wiener smoothing: the per-slot work is the matrix-vector apply per
+    antenna pair.
+
+    The (n_sc x n_sc) filter depends only on ``(n_sc, corr_len,
+    noise_var)``.  A served step reads it from ``state["wiener_w"]``,
+    built once per noise variance by the serving filter cache
+    (:mod:`repro.serve.exec_registry`) outside the step; only when the
+    slot carries no ``wiener_w`` (``pipeline.run`` on plain slots) is it
+    built here, in the step's graph.  The filter is not passed on as an
+    output of the step."""
 
     def apply(state):
-        state["h_hat"] = classical.mmse_smooth_link(
-            state["h_ls"], state["noise_var"], corr_len=corr_len
-        )
+        w = state.pop("wiener_w", None)
+        if w is None:
+            w = classical.wiener_filter(
+                cfg.n_subcarriers, corr_len, state["noise_var"]
+            )
+        state["h_hat"] = classical.mmse_smooth_link(state["h_ls"], w)
         return state
 
     def cycles():
@@ -294,7 +314,7 @@ def mmse_che_stage(cfg: ofdm.GridConfig, corr_len: float = 16.0) -> RxStage:
             ),
         )
 
-    return RxStage("mmse_che", "PE", apply, cycles)
+    return RxStage("mmse_che", "PE", apply, cycles, wiener_corr_len=corr_len)
 
 
 def _broadcast_h(h_est, n_sym):

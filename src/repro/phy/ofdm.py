@@ -326,7 +326,6 @@ def make_link_slot(
     ) if h.shape[1] == 1 else h
     y = jnp.einsum("bmsrt,bmst->bmsr", hb, x)
     snr = 10.0 ** (snr_db / 10.0)
-    noise_var = cfg.n_tx / snr
     if interferer_db:
         icfg = dataclasses.replace(cfg, n_tx=1)
         for p_db, k_i in zip(interferer_db,
@@ -349,9 +348,7 @@ def make_link_slot(
             si = jnp.exp(1j * (jnp.pi / 4 + jnp.pi / 2 * qi))
             amp = 10.0 ** (p_db / 20.0)
             y = y + amp * hib[..., 0] * si[..., None]
-        noise_var = noise_var + sum(
-            10.0 ** (p / 10.0) for p in interferer_db
-        )
+    noise_var = noise_variance(cfg.n_tx, snr_db, interferer_db)
     kn1, kn2 = jax.random.split(kn)
     noise = jax.random.normal(kn1, y.shape) + 1j * jax.random.normal(
         kn2, y.shape
@@ -364,6 +361,19 @@ def make_link_slot(
         "noise_var": jnp.asarray(noise_var, jnp.float32),
         "pilot_seq": seq, "pilot_masks": pm_tx, "data_mask": ~union,
     }
+
+
+def noise_variance(n_tx: int, snr_db, interferer_db: tuple = ()):
+    """A link slot's ``noise_var`` side info: thermal noise ``n_tx / snr``
+    plus each co-channel interferer's mean power per receive antenna.
+    Host floats in, a host float out (:attr:`LinkScenario.noise_var`);
+    traced in, traced out (:func:`make_link_slot`)."""
+    noise_var = n_tx / 10.0 ** (snr_db / 10.0)
+    if interferer_db:
+        noise_var = noise_var + sum(
+            10.0 ** (p / 10.0) for p in interferer_db
+        )
+    return noise_var
 
 
 def make_mimo_slot(key: jax.Array, cfg: GridConfig, batch: int, snr_db: float):

@@ -70,6 +70,14 @@ class LinkScenario:
         return ofdm.make_modem(self.modulation)
 
     @property
+    def noise_var(self) -> float:
+        """The ``noise_var`` side info of this scenario's slots, on the
+        host (:func:`repro.phy.ofdm.noise_variance`)."""
+        return ofdm.noise_variance(
+            self.grid.n_tx, self.snr_db, self.interferer_db
+        )
+
+    @property
     def is_mimo(self) -> bool:
         return self.grid.n_tx > 1 or self.grid.n_rx > 1
 

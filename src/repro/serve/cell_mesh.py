@@ -66,7 +66,8 @@ from repro.launch.mesh import make_cell_mesh
 from repro.phy import link as _link
 from repro.phy.scenarios import LinkScenario, get_scenario
 from repro.serve.exec_registry import (
-    ExecStats, PowerOfTwoBuckets, get_registry, slot_schema, template_slot,
+    ExecStats, FilterStats, PowerOfTwoBuckets, get_filter_cache,
+    get_registry, slot_schema, template_slot,
 )
 from repro.serve.runtime import (
     BATCHED_KEYS, CellLoop, ClosedLoopReport, JobCounter, PhyServeReport,
@@ -279,6 +280,7 @@ class CellMeshEngine:
         self._ledger = SlotLedger()
         self.registry = registry if registry is not None else get_registry()
         self.exec_stats = ExecStats()
+        self.filter_stats = FilterStats()
         self.step_times: list[float] = []
         if prebuild:
             for group in self.groups:
@@ -290,7 +292,7 @@ class CellMeshEngine:
         scn = self.cells[group.cell_idxs[0]].scenario
         req = SlotRequest(user_id=-1, slot=template_slot(scn))
         lane = _Lane(cell_idx=None, reqs=[req], pad=self.batch_size - 1)
-        return self._stage([lane] * len(group.cell_idxs))
+        return self._stage(group, [lane] * len(group.cell_idxs))
 
     def _group_step(self, group: _Group, example: dict):
         """Acquire ``group``'s AOT step for ``example``'s slot schema
@@ -393,8 +395,9 @@ class CellMeshEngine:
         return steps
 
     # -- staging (host side; overlapped with device compute) --------------
-    def _stage(self, lanes: list[_Lane]) -> dict:
-        """Stack one step's slots to (n_lanes, batch, ...) sharded arrays."""
+    def _stage(self, group: _Group, lanes: list[_Lane]) -> dict:
+        """Stack one step's slots to (n_lanes, batch, ...) sharded arrays,
+        with the lanes' Wiener filters (:func:`_lane_filters`)."""
         per_lane = [
             stack_slots([r.slot for r in lane.reqs], lane.pad, xp=np)
             for lane in lanes
@@ -409,7 +412,10 @@ class CellMeshEngine:
             stacked, self.mesh, batched_keys=BATCHED_KEYS
         )
         return {
-            k: jax.device_put(v, shardings[k]) for k, v in stacked.items()
+            **{k: jax.device_put(v, shardings[k])
+               for k, v in stacked.items()},
+            **_lane_filters(group.pipeline, stacked["noise_var"], self.mesh,
+                            stats=self.filter_stats, registry=self.registry),
         }
 
     # -- serving ----------------------------------------------------------
@@ -442,13 +448,13 @@ class CellMeshEngine:
             plan = self._plan(group)
             if not plan:
                 continue
-            staged = self._stage(plan[0])
+            staged = self._stage(group, plan[0])
             step = self._group_step(group, staged)
             t_group = 0.0
             for i, lanes in enumerate(plan):
                 t0 = time.perf_counter()
                 state = step(staged)  # async dispatch
-                staged = (self._stage(plan[i + 1])
+                staged = (self._stage(group, plan[i + 1])
                           if i + 1 < len(plan) else None)
                 state = wait(state)
                 dt = time.perf_counter() - t0
@@ -552,6 +558,24 @@ class CellMeshEngine:
             first_tick_s=first_s,
             steady_tick_s=steady_s,
         )
+
+
+def _lane_filters(pipeline, noise_vars, mesh, *, stats: FilterStats,
+                  registry) -> dict:
+    """A mesh step's ``wiener_w``: the device-resident ``(lanes, n_sc,
+    n_sc)`` stack of each lane's Wiener filter at its noise variance
+    (``noise_vars``, staged on the host), from the process-wide filter
+    cache and sharded like the other per-lane side info.  Empty when the
+    pipeline has no Wiener stage."""
+    if pipeline.wiener_corr_len is None:
+        return {}
+    n_sc = pipeline.scenario.grid.n_subcarriers
+    w = jax.ShapeDtypeStruct((len(noise_vars), n_sc, n_sc), np.complex64)
+    return {"wiener_w": get_filter_cache().lane_stack(
+        n_sc, pipeline.wiener_corr_len, noise_vars,
+        shd.cell_slot_shardings({"w": w}, mesh)["w"],
+        stats=stats, registry=registry,
+    )}
 
 
 # ---------------------------------------------------------------------------
@@ -828,6 +852,7 @@ class MeshSlotScheduler:
 
         self.registry = registry if registry is not None else get_registry()
         self.exec_stats = ExecStats()
+        self.filter_stats = FilterStats()
         self.slot_gen = SlotGenerator(registry=self.registry,
                                       stats=self.exec_stats)
         self._uid_bases: list[int] = []
@@ -1014,11 +1039,14 @@ class MeshSlotScheduler:
         delegated to the pluggable :class:`BucketPolicy`."""
         return self.bucket_policy.bucket_for(n_lanes)
 
-    def _stage(self, lanes: list[_ClosedLane],
+    def _stage(self, gi: int, mcs: int, lanes: list[_ClosedLane],
                bucket: Optional[int] = None) -> dict:
-        """Stack one step's lanes to sharded (n_lanes, batch, ...) arrays,
-        padding with filler lanes (replaying lane 0) to the policy's lane
-        bucket."""
+        """Stack one step's lanes of group ``gi``'s rung ``mcs`` to
+        sharded (n_lanes, batch, ...) arrays, padding with filler lanes
+        (replaying lane 0) to the policy's lane bucket.  A rung with a
+        Wiener stage also gets ``wiener_w``, the stack of each lane's
+        filter at its noise variance as staged here
+        (:func:`_lane_filters`)."""
         if bucket is None:
             bucket = self._bucket(len(lanes))
         with span("serve.stage", lanes=len(lanes), bucket=bucket):
@@ -1034,8 +1062,12 @@ class MeshSlotScheduler:
                 stacked, self.mesh, batched_keys=BATCHED_KEYS
             )
             return {
-                k: jax.device_put(v, shardings[k])
-                for k, v in stacked.items()
+                **{k: jax.device_put(v, shardings[k])
+                   for k, v in stacked.items()},
+                **_lane_filters(self.groups[gi].pipelines[mcs],
+                                stacked["noise_var"], self.mesh,
+                                stats=self.filter_stats,
+                                registry=self.registry),
             }
 
     # -- the lockstep TTI loop --------------------------------------------
@@ -1081,10 +1113,10 @@ class MeshSlotScheduler:
         async-dispatch window), warmups are untimed."""
         if not items:
             return
-        staged = self._stage(items[0][1])
+        staged = self._stage(*items[0][0], items[0][1])
         for i, ((gi, mcs), lanes) in enumerate(items):
             prefetch = (
-                (lambda j=i + 1: self._stage(items[j][1]))
+                (lambda j=i + 1: self._stage(*items[j][0], items[j][1]))
                 if i + 1 < len(items) else None
             )
             staged = self._dispatch(gi, mcs, lanes, staged, stats,
@@ -1153,7 +1185,7 @@ class MeshSlotScheduler:
                     )],
                     pad=self.batch_size - 1,
                 )
-                staged = self._stage([lane], bucket=spec.lanes)
+                staged = self._stage(gi, mcs, [lane], bucket=spec.lanes)
                 self._step_for(gi, mcs, spec.lanes, staged)
         for loop in self.loops:
             loop.prebuild_slots()

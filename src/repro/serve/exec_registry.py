@@ -43,6 +43,10 @@ This module centralizes all of it:
   :meth:`repro.phy.scenarios.LinkScenario.make_batch`) the runtime uses.
   The closed loop's compiled slot generators
   (:class:`repro.serve.runtime.SlotGenerator`) are registry entries too.
+* :class:`FilterCache` — the Wiener filters the served steps read, built
+  once per noise variance (by a registry executable) and kept on the
+  device in a byte-bounded LRU beside the executables, so no step solves
+  for its filter.
 
 The process-wide default registry (:func:`get_registry`) is shared by
 every engine in the process — two schedulers serving the same ladder at
@@ -62,6 +66,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.monitoring
+import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 from jax.experimental.compilation_cache import compilation_cache
@@ -76,10 +81,12 @@ _CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 __all__ = [
     "BucketPolicy", "CostModelBuckets", "ExecKey", "ExecRegistry",
-    "ExecStats", "FixedBuckets", "PowerOfTwoBuckets", "default_cache_dir",
-    "disable_persistent_cache",
-    "enable_persistent_cache", "exec_key_for", "get_registry",
-    "set_registry", "slot_schema", "template_batch", "template_slot",
+    "ExecStats", "FilterCache", "FilterStats", "FixedBuckets",
+    "PowerOfTwoBuckets", "default_cache_dir", "disable_persistent_cache",
+    "enable_persistent_cache", "exec_key_for", "filter_program",
+    "get_filter_cache",
+    "get_registry", "set_filter_cache", "set_registry", "slot_schema",
+    "template_batch", "template_slot",
 ]
 
 
@@ -242,15 +249,17 @@ class ExecStats:
 
 
 def slot_schema(slot: dict) -> str:
-    """Compact structural tag of a slot batch: its batched keys.
+    """Compact structural tag of a slot batch: its batched keys, and
+    ``wiener_w`` when it carries a prebuilt Wiener filter.
 
     Side-info keys are scenario-determined (the scenario is already in
     the key); the batched keys are what distinguish open-loop slots from
-    HARQ slots carrying ``rv`` + ``prior_llr``.
+    HARQ slots carrying ``rv`` + ``prior_llr``, and a step given its
+    filter is another program than one that builds it.
     """
     from repro.serve.runtime import BATCHED_KEYS
 
-    return "+".join(k for k in BATCHED_KEYS if k in slot)
+    return "+".join(k for k in BATCHED_KEYS + ("wiener_w",) if k in slot)
 
 
 def _pipeline_variant(pipeline) -> str:
@@ -496,9 +505,44 @@ class CostModelBuckets(FixedBuckets):
 # The registry
 # ---------------------------------------------------------------------------
 
+# step inputs the filter cache keeps across steps: never donated
+RESIDENT_KEYS = ("wiener_w",)
+
+
+def _split(batch) -> tuple:
+    """``batch`` as (step input, resident input): a dict batch's
+    :data:`RESIDENT_KEYS` ride the second, anything else is all input."""
+    if not isinstance(batch, dict):
+        return batch, {}
+    return ({k: v for k, v in batch.items() if k not in RESIDENT_KEYS},
+            {k: batch[k] for k in RESIDENT_KEYS if k in batch})
+
+
+class _Step:
+    """The AOT-compiled ``fn(batch)``, with the batch's
+    :data:`RESIDENT_KEYS` in a second argument that is never donated.
+    Donated with the rest of the batch, a cached filter could be consumed
+    by the step (on a multi-device mesh every donated input is a buffer
+    XLA may reuse) and deleted from the cache.  Called like ``fn``, and
+    otherwise used like the compiled executable it wraps."""
+
+    def __init__(self, fn: Callable, example, jit_kw: dict):
+        def step(batch, resident):
+            return fn({**batch, **resident} if resident else batch)
+
+        self.compiled = jax.jit(step, **jit_kw).lower(
+            *_split(example)).compile()
+
+    def __call__(self, batch):
+        return self.compiled(*_split(batch))
+
+    def __getattr__(self, name: str):
+        return getattr(self.compiled, name)
+
+
 @dataclasses.dataclass
 class _Entry:
-    compiled: object  # jax Compiled
+    compiled: _Step
     compile_s: float
     from_disk: bool
 
@@ -569,7 +613,7 @@ class ExecRegistry:
             if self.persistent:
                 enable_persistent_cache(self.cache_dir)
             try:
-                compiled = jax.jit(fn, **jit_kw).lower(example).compile()
+                compiled = _Step(fn, example, jit_kw)
             finally:
                 if self.persistent:
                     disable_persistent_cache()
@@ -647,3 +691,140 @@ def set_registry(reg: Optional[ExecRegistry]) -> None:
     """Install (or with ``None`` drop) the process-wide registry."""
     global _DEFAULT
     _DEFAULT = reg
+
+
+# ---------------------------------------------------------------------------
+# Wiener filters: built once per noise variance, resident on the device
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class FilterStats:
+    """Per-engine :class:`FilterCache` accounting: filters built (a
+    Wiener solve on the device each), lookups a resident filter
+    satisfied, and filters this engine's builds pushed out of the
+    cache."""
+    builds: int = 0
+    hits: int = 0
+    evictions: int = 0
+
+
+class FilterCache:
+    """Byte-bounded LRU of device-resident Wiener filters.
+
+    A filter ``W = (R + sigma^2 I)^-1 R``
+    (:func:`repro.phy.classical.wiener_filter`) depends only on
+    ``(n_sc, corr_len, noise_var)``; a served step reads it as the slot's
+    ``wiener_w`` instead of solving for it every step.  Keys hold the
+    noise variance as float32, read from a value the host already has
+    (the slot's side info, or its scenario), so a lookup never waits on
+    the device.  A mesh step's ``(lanes, n_sc, n_sc)`` stack of lane
+    filters (:meth:`lane_stack`) is made anew each step from these
+    entries, and is not itself kept.
+
+    Each build is one call of an AOT executable from the registry (the
+    solve, with R computed on the device from the subcarrier positions:
+    no ``n_sc x n_sc`` constant in any program) under a
+    ``serve.wiener_build`` span; a hit opens no span.  Entries leave the
+    least recently used first once their bytes pass ``max_bytes`` (the
+    newest always stays).  One filter at 3276 subcarriers is 86 MB.
+    """
+
+    def __init__(self, max_bytes: int = 512 << 20):
+        self.max_bytes = int(max_bytes)
+        self.nbytes = 0
+        self.stats = FilterStats()  # cache-wide accounting
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def _count(self, field: str, stats: Optional[FilterStats]) -> None:
+        for s in (self.stats, stats):
+            if s is not None:
+                setattr(s, field, getattr(s, field) + 1)
+
+    def filter(self, n_sc: int, corr_len: float, noise_var, *,
+               stats: Optional[FilterStats] = None,
+               registry: Optional[ExecRegistry] = None):
+        """The ``(n_sc, n_sc)`` complex64 filter at ``noise_var`` (any
+        host scalar; a device array is read to the host)."""
+        nv = float(np.float32(noise_var))
+        key = (int(n_sc), float(corr_len), nv)
+        w = self._entries.get(key)
+        if w is not None:
+            self._entries.move_to_end(key)
+            self._count("hits", stats)
+            return w
+        with span("serve.wiener_build", n_sc=n_sc):
+            w = _build_filter(n_sc, corr_len, nv, registry)
+        self._count("builds", stats)
+        self._entries[key] = w
+        self.nbytes += w.nbytes
+        while self.nbytes > self.max_bytes and len(self._entries) > 1:
+            _, old = self._entries.popitem(last=False)
+            self.nbytes -= old.nbytes
+            self._count("evictions", stats)
+        return w
+
+    def lane_stack(self, n_sc: int, corr_len: float, noise_vars, sharding,
+                   *, stats: Optional[FilterStats] = None,
+                   registry: Optional[ExecRegistry] = None):
+        """The ``(lanes, n_sc, n_sc)`` stack of lane ``i``'s filter at
+        ``noise_vars[i]``, placed with ``sharding``: one lookup per
+        distinct noise variance, stacked on the device."""
+        nvs = [float(np.float32(v)) for v in noise_vars]
+        ws = {nv: self.filter(n_sc, corr_len, nv, stats=stats,
+                              registry=registry)
+              for nv in dict.fromkeys(nvs)}
+        return jax.device_put(_stack(tuple(ws[nv] for nv in nvs)), sharding)
+
+
+_stack = jax.jit(jnp.stack)
+
+
+def filter_program(n_sc: int, corr_len: float) -> Callable:
+    """The filter builder's program: ``{"pos", "noise_var"}`` -> the
+    ``(n_sc, n_sc)`` filter.  The noise variance and the subcarrier
+    positions are its arguments, so neither R nor any other ``n_sc x
+    n_sc`` array is a constant of the program."""
+    from repro.phy import classical
+
+    def fn(args: dict):
+        return classical.wiener_filter(n_sc, corr_len, args["noise_var"],
+                                       pos=args["pos"])
+
+    return fn
+
+
+def _build_filter(n_sc: int, corr_len: float, noise_var: float,
+                  registry: Optional[ExecRegistry]):
+    """One Wiener filter on the device, by the registry's builder
+    executable (:func:`filter_program`) for ``(n_sc, corr_len)``."""
+    args = {"pos": np.arange(n_sc, dtype=np.float32),
+            "noise_var": np.float32(noise_var)}
+    key = ExecKey(
+        scenario=f"wiener:{n_sc}", receiver="wiener_filter",
+        precision="fp32", batch=1, lanes=0, backend=jax.default_backend(),
+        variant=repr(float(corr_len)),
+    )
+    reg = registry if registry is not None else get_registry()
+    return reg.acquire(key, filter_program(n_sc, corr_len), args)(args)
+
+
+_FILTERS: Optional[FilterCache] = None
+
+
+def get_filter_cache() -> FilterCache:
+    """The process-wide :class:`FilterCache`, shared by every engine (a
+    runner made per ``PhyServeEngine.run()`` call finds the filters of
+    the runs before it)."""
+    global _FILTERS
+    if _FILTERS is None:
+        _FILTERS = FilterCache()
+    return _FILTERS
+
+
+def set_filter_cache(cache: Optional[FilterCache]) -> None:
+    """Install (or with ``None`` drop) the process-wide filter cache."""
+    global _FILTERS
+    _FILTERS = cache

@@ -125,4 +125,5 @@ class PhyServeEngine:
                 batch_size=self.batch_size, wall_s=runner.wall_s,
                 exec_stats=runner.exec_stats,
                 batch_times=runner.batch_times,
+                filter_stats=runner.filter_stats,
             )

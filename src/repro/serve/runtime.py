@@ -60,7 +60,8 @@ import numpy as np
 
 from repro.phy import link as _link
 from repro.serve.exec_registry import (
-    ExecKey, ExecStats, get_registry, slot_schema, template_batch,
+    ExecKey, ExecStats, FilterStats, get_filter_cache, get_registry,
+    slot_schema, template_batch,
 )
 from repro.serve.trace import shape_counts, span, step_window, wait
 
@@ -120,6 +121,11 @@ class PhyServeReport:
     cache_hits: int = 0
     first_tick_s: Optional[float] = None
     steady_tick_s: Optional[float] = None
+    # Wiener filter cache (exec_registry.FilterCache): filters this run
+    # built, lookups a resident filter served, filters its builds evicted
+    filter_builds: int = 0
+    filter_hits: int = 0
+    filter_evictions: int = 0
 
     def summary(self) -> str:
         parts = [
@@ -153,6 +159,11 @@ class PhyServeReport:
             parts.append(
                 f"compile={self.compile_time_s:.2f}s "
                 f"({self.executables_compiled}x/{self.cache_hits}hit)"
+            )
+        if self.filter_builds or self.filter_hits:
+            parts.append(
+                f"wiener={self.filter_builds}built/{self.filter_hits}hit"
+                f"/{self.filter_evictions}evicted"
             )
         return "  ".join(parts)
 
@@ -309,7 +320,8 @@ def first_steady(times) -> tuple:
 def build_serve_report(pipeline: _link.ReceiverPipeline, scenario,
                        metric_dicts, *, n_slots: int, n_batches: int,
                        batch_size: int, wall_s: float,
-                       exec_stats=None, batch_times=()) -> PhyServeReport:
+                       exec_stats=None, batch_times=(),
+                       filter_stats=None) -> PhyServeReport:
     """Aggregate served-slot metrics into a :class:`PhyServeReport` —
     shared by the single-cell engine and the mesh's per-cell reports so
     the two always agree (incl. the goodput definition)."""
@@ -357,6 +369,9 @@ def build_serve_report(pipeline: _link.ReceiverPipeline, scenario,
         cache_hits=exec_stats.cache_hits if exec_stats else 0,
         first_tick_s=first_s,
         steady_tick_s=steady_s,
+        filter_builds=filter_stats.builds if filter_stats else 0,
+        filter_hits=filter_stats.hits if filter_stats else 0,
+        filter_evictions=filter_stats.evictions if filter_stats else 0,
     )
 
 
@@ -375,6 +390,11 @@ class BatchRunner:
     first chunk and is a no-op once the executable is resident.  Compile
     accounting lands in ``exec_stats``; per-batch latencies in
     ``batch_times`` (first vs steady state on the report).
+
+    A pipeline with a Wiener stage gets its filter as the batch's
+    ``wiener_w``, from the process-wide
+    :class:`~repro.serve.exec_registry.FilterCache` (lookups in
+    ``filter_stats``); :meth:`prepare` builds it before the window.
     """
 
     def __init__(self, pipeline: _link.ReceiverPipeline, batch_size: int,
@@ -388,11 +408,33 @@ class BatchRunner:
         self.batch_times: list[float] = []
         self._execs: dict = {}  # slot schema -> AOT-compiled step
         self.span_counts = shape_counts(pipeline.scenario)
+        self.filter_stats = FilterStats()
+
+    def with_filter(self, batch: dict, noise_var=None) -> dict:
+        """``batch`` with the Wiener filter its step reads as
+        ``wiener_w``, looked up in the filter cache; ``batch`` itself when
+        the pipeline has no Wiener stage or the filter is there already.
+
+        ``noise_var`` is the batch's noise variance as the host holds it;
+        None takes the batch's own ``noise_var`` side info (a host scalar
+        on slots made there, as the benchmark's pools are; a device array
+        is read to the host)."""
+        corr_len = self.pipeline.wiener_corr_len
+        if corr_len is None or "wiener_w" in batch:
+            return batch
+        w = get_filter_cache().filter(
+            self.pipeline.scenario.grid.n_subcarriers, corr_len,
+            batch["noise_var"] if noise_var is None else noise_var,
+            stats=self.filter_stats, registry=self.registry,
+        )
+        return {**batch, "wiener_w": w}
 
     def prepare(self, batch: dict):
-        """Acquire the AOT step for ``batch``'s slot structure (no
-        execution).  Idempotent per schema; the registry satisfies repeat
-        acquisitions in memory and cold ones from the persistent cache."""
+        """Acquire the AOT step for ``batch``'s slot structure and the
+        Wiener filter of its noise variance (no execution).  Idempotent
+        per schema; the registry satisfies repeat acquisitions in memory
+        and cold ones from the persistent cache."""
+        batch = self.with_filter(batch)
         schema = slot_schema(batch)
         step = self._execs.get(schema)
         if step is None:
@@ -409,8 +451,9 @@ class BatchRunner:
         ))
 
     def _step(self, batch: dict) -> dict:
-        """Run ``batch`` through the resident executable (acquiring it
-        first if a caller skipped :meth:`prepare`)."""
+        """Run ``batch`` (with its filter, :meth:`with_filter`) through
+        the resident executable (acquiring it first if a caller skipped
+        :meth:`prepare`)."""
         return self.prepare(batch)(batch)
 
     def _execute(self, batch: dict) -> dict:
@@ -422,17 +465,18 @@ class BatchRunner:
         self.batch_times.append(window["dt"])
         return state
 
-    def run_batch(self, reqs: list) -> dict:
+    def run_batch(self, reqs: list, noise_var=None) -> dict:
         """Serve one chunk of requests; returns the raw pipeline state.
 
         Marks each request done with its per-slot metrics; padded tail
-        results are discarded.
+        results are discarded.  ``noise_var``: see :meth:`with_filter`.
         """
         with span("serve.batch", slots=len(reqs), **self.span_counts):
             with span("serve.stack"):
                 batch = stack_slots(
                     [r.slot for r in reqs], self.batch_size - len(reqs)
                 )
+            batch = self.with_filter(batch, noise_var)
             state = self._execute(batch)
             self.n_batches += 1
             with span("serve.slot_metrics"):
@@ -1280,7 +1324,10 @@ class SlotScheduler:
                 )
                 for u, job in pairs
             ]
-            state = runner.run_batch(reqs)
+            # a batch shares one SNR (plan_batches): its scenario gives
+            # the filter's noise variance without reading the slots
+            state = runner.run_batch(reqs, noise_var=loop._tx_scenario(
+                self.rungs[mcs], pairs[0][0].snr_db).noise_var)
             loop.n_batches += 1
             crc_ok = np.asarray(state["crc_ok"])
             cw_llr = np.asarray(state["cw_llr"])

@@ -578,7 +578,7 @@ class Supervisor(MeshSlotScheduler):
                 self.step_retries += 1
                 if self.retry_backoff_s:
                     time.sleep(self.retry_backoff_s * 2 ** attempt)
-                staged = self._stage(lanes)  # clean re-stage
+                staged = self._stage(gi, mcs, lanes)  # clean re-stage
         if not prefetched:
             nxt = prefetch() if prefetch is not None else None
 
@@ -608,7 +608,7 @@ class Supervisor(MeshSlotScheduler):
             for li in bad:
                 self._cell_degraded[lanes[li].cell_idx] += 1
                 self._charge_fault(lanes[li].cell_idx)
-            clean = self._stage(lanes)
+            clean = self._stage(gi, mcs, lanes)
             ref = self._ref_step(gi, mcs, bucket, clean)
             with step_window(self, **counts):
                 out = wait(ref(clean))

@@ -27,7 +27,9 @@ code that drives the loop.  The spans and what they hold:
   ``BatchRunner.run_batch``;
 * ``serve.report``: a report built after ``PhyServeEngine.run``;
 * ``serve.acquire`` (``compiled``, ``cache_hit``): an executable built,
-  or loaded from the persistent cache, by the registry.
+  or loaded from the persistent cache, by the registry;
+* ``serve.wiener_build`` (``n_sc``): a Wiener filter built by the filter
+  cache; a hit opens none.
 """
 from __future__ import annotations
 

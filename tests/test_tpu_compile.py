@@ -13,17 +13,22 @@ describe at collection would break the other test workers.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from repro.distributed import sharding as shd
 from repro.kernels import ldpc, rx_fused
 from repro.phy import link
 from repro.phy.scenarios import get_scenario
-from repro.serve.exec_registry import template_batch
+from repro.serve.exec_registry import (
+    ExecRegistry, filter_program, template_batch,
+)
+from repro.serve.runtime import BATCHED_KEYS
 
 
 @pytest.fixture(scope="module")
@@ -186,7 +191,8 @@ def test_mmse_detect_demap_compiles_at_100mhz(one_chip):
 def test_served_mesh_step_compiles(one_chip, on_chip, scenario, options,
                                    kernels):
     """The closed-loop mesh step as served: ``vmap(pipeline._apply)``
-    over 4 lanes of batch-8 HARQ slots (rv + prior_llr)."""
+    over 4 lanes of batch-8 HARQ slots (rv + prior_llr), each lane with
+    its Wiener filter (``wiener_w``)."""
     scn = get_scenario(scenario)
     pipe = link.build_pipeline("classical", scn, **options)
     lane = template_batch(scn, 8, harq=True)
@@ -194,6 +200,56 @@ def test_served_mesh_step_compiles(one_chip, on_chip, scenario, options,
         k: _spec((4,) + np.shape(v), np.asarray(v).dtype, one_chip)
         for k, v in lane.items()
     }
+    n_sc = scn.grid.n_subcarriers
+    staged["wiener_w"] = _spec((4, n_sc, n_sc), jnp.complex64, one_chip)
     names = _mosaic_kernels(jax.vmap(pipe._apply), staged)
     for k in kernels:
         assert any(k in n for n in names), (k, names)
+
+
+def test_wiener_filter_builder_compiles(one_chip):
+    """The filter cache's builder program at 300 subcarriers (25 PRB):
+    the linear solve, with R computed from the subcarrier positions it is
+    given, so the program holds no 300 x 300 constant."""
+    args = {"pos": _spec((300,), jnp.float32, one_chip),
+            "noise_var": _spec((), jnp.float32, one_chip)}
+    text = jax.jit(filter_program(300, 16.0)).lower(args).compile().as_text()
+    assert "jit(solve)" in text
+    assert not re.search(r"\[300,300\]\S* constant\(", text)
+
+
+def test_served_mesh_step_compiles_donated(topo, on_chip):
+    """The mesh step as the registry builds it for a chip: shard-mapped
+    over a (cell, batch) mesh of one device, 4 lanes of batch-8 HARQ
+    slots donated, and the lanes' Wiener filters in a second argument
+    that is not donated."""
+    scn = get_scenario("siso-qam16-r12-snr15")
+    pipe = link.build_pipeline("classical", scn, fused=True)
+    n_sc = scn.grid.n_subcarriers
+    shapes = {
+        k: jax.ShapeDtypeStruct((4,) + np.shape(v), np.asarray(v).dtype)
+        for k, v in template_batch(scn, 8, harq=True).items()
+    }
+    shapes["wiener_w"] = jax.ShapeDtypeStruct((4, n_sc, n_sc),
+                                              jnp.complex64)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("cell", "batch"))
+    shardings = shd.cell_slot_shardings(shapes, mesh,
+                                        batched_keys=BATCHED_KEYS)
+    staged = {k: _spec(v.shape, v.dtype, shardings[k])
+              for k, v in shapes.items()}
+    assert all(isinstance(v.sharding, NamedSharding)
+               for v in staged.values())
+    step = ExecRegistry(persistent=False).acquire_pipeline_step(
+        pipe, staged, batch=8, lanes=4, donate=True,
+    )
+    names = [
+        line.split('op_name="', 1)[1].split('"', 1)[0]
+        for line in step.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    for k in ("rx_ls_che", "rx_detect_demap", "ldpc_decode"):
+        assert any(k in n for n in names), (k, names)
+    (batch, resident), _ = step.args_info
+    assert all(a.donated for a in jax.tree.leaves(batch))
+    assert set(resident) == {"wiener_w"}
+    assert not resident["wiener_w"].donated
